@@ -591,20 +591,14 @@ def obs_sweep(print_fn=print, arch: str = "qwen2-0.5b", slots: int = 4,
 
 
 # ---------------------------------------------------------------------------
-# meshed serving: TP decode scaling + block-locality gate (subprocess)
+# meshed serving: TP decode scaling + block-locality gate
 # ---------------------------------------------------------------------------
 
-def _mesh_child(cfg_json: str) -> int:
-    """Child-process body for ``mesh_sweep``: serve one deterministic
-    request stream on the requested mesh and print a single
-    ``MESH_CHILD_RESULT {json}`` line. Runs in its own process because the
-    forced-host-platform device count must be set before jax initializes."""
-    import jax
-
+def _mesh_point(c: dict) -> dict:
+    """Serve one deterministic request stream on the requested mesh."""
     from repro.launch.mesh import make_debug_mesh
     from repro.runtime.server import LMServer
 
-    c = json.loads(cfg_json)
     cfg, model, params, cap = _build(c["arch"], c["policy"],
                                      c["prompt_len"], c["max_tokens"])
     mesh = (make_debug_mesh(c["mesh_data"], c["mesh_model"])
@@ -640,13 +634,30 @@ def _mesh_child(cfg_json: str) -> int:
         "spilled": a.spilled_allocs,
         "remote_fraction": remote_peak,
     }
-    print("MESH_CHILD_RESULT " + json.dumps(out))
+    return out
+
+
+def _mesh_child(cfg_json: str) -> int:
+    """Child-process body for ``mesh_sweep`` on a CPU host: one
+    :func:`_mesh_point`, printed as a single ``MESH_CHILD_RESULT {json}``
+    line. A child because the forced-host-platform device count must be
+    set before jax initializes."""
+    print("MESH_CHILD_RESULT " + json.dumps(_mesh_point(json.loads(cfg_json))))
     return 0
 
 
-def _spawn_mesh_child(child_cfg: dict, timeout: int = 1200) -> dict:
+def _run_mesh_point(child_cfg: dict, timeout: int = 1200) -> dict:
+    """On an accelerator host the points run in this process over
+    ``jax.devices()``: this process holds the chips, so a child could not
+    get them. On CPU each point is a child with its own forced device
+    count."""
     import os
     import subprocess
+
+    import jax
+
+    if jax.default_backend() != "cpu":
+        return _mesh_point(child_cfg)
 
     env = dict(os.environ)
     n_dev = max(child_cfg["mesh_data"] * child_cfg["mesh_model"], 1)
@@ -670,8 +681,8 @@ def mesh_sweep(print_fn=print, arch: str = "qwen2-0.5b",
                tp_list=(1, 2, 4), prompt_len: int = 12,
                max_tokens: int = 16, n_requests: int = 6,
                enforce: bool = True):
-    """Meshed-serving rows (each point is a fresh subprocess with its own
-    forced device count):
+    """Meshed-serving rows (on CPU each point is a fresh subprocess with its
+    own forced device count; on an accelerator host, in-process):
 
       * ``tp{t}_tok_s`` — decode throughput of the paged engine at
         model-parallel degree t (t=1 is the single-device baseline). On the
@@ -692,7 +703,7 @@ def mesh_sweep(print_fn=print, arch: str = "qwen2-0.5b",
     results = {}
     tok0 = None
     for t in tp_list:
-        r = _spawn_mesh_child(dict(base, mesh_data=1, mesh_model=t))
+        r = _run_mesh_point(dict(base, mesh_data=1, mesh_model=t))
         results[f"tp{t}_tok_s"] = r["tok_s"]
         print_fn(f"serving_mesh,tp{t}_tok_s,{r['tok_s']:.2f},"
                  f"decode+prefill tok/s at model={t} (host-platform "
@@ -704,8 +715,8 @@ def mesh_sweep(print_fn=print, arch: str = "qwen2-0.5b",
                 f"meshed engine at tp={t} diverged from the tp=1 greedy "
                 f"token stream")
 
-    loc = _spawn_mesh_child(dict(base, mesh_data=2, mesh_model=1))
-    rr = _spawn_mesh_child(dict(base, mesh_data=2, mesh_model=1,
+    loc = _run_mesh_point(dict(base, mesh_data=2, mesh_model=1))
+    rr = _run_mesh_point(dict(base, mesh_data=2, mesh_model=1,
                                 placement="round_robin"))
     results.update(locality_spilled=loc["spilled"], rr_spilled=rr["spilled"],
                    locality_remote=loc["remote_fraction"],

@@ -22,6 +22,16 @@ def _fold_x(x, policy):
     return xg.reshape(xg.shape[:-2] + (xg.shape[-2] * xg.shape[-1],))
 
 
+def _dot_dtype(policy):
+    """The folded-scale matmul's operand dtype. BFP(b_m <= 6) values are
+    exact in bfloat16, so ``compute_dtype="bfloat16"`` changes no value,
+    only bytes and MXU passes. The CPU runtime has no bf16 x bf16 -> f32
+    dot kernel for every shape, so there the same values go in as f32."""
+    if policy.compute_dtype != "bfloat16" or jax.default_backend() == "cpu":
+        return jnp.float32
+    return jnp.bfloat16
+
+
 @register_fn("mirage_fast",
              description="BFP quantize -> fold scales -> one MXU matmul",
              supports_weight_stationary=True)
@@ -29,7 +39,7 @@ def _matmul_mirage_fast(x, w, policy, *, key=None):
     if policy.use_pallas:
         from repro.kernels import ops as kops
         return kops.mirage_matmul_fused(x, w, policy)
-    dt = jnp.bfloat16 if policy.compute_dtype == "bfloat16" else jnp.float32
+    dt = _dot_dtype(policy)
     xq = _fold_x(x, policy)                    # (..., Kpad)
     if policy.assume_quantized_weights:
         # weight operand already on the BFP grid (weight-stationary quant:

@@ -61,6 +61,26 @@ def check_overflow_bound(b_m: int, g: int, moduli: Tuple[int, ...]) -> None:
         )
 
 
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """Whether Pallas kernels run in interpret mode.
+
+    ``None`` follows ``jax.default_backend()``: interpreted on CPU (where
+    Mosaic cannot compile), compiled everywhere else. Asking for interpret
+    mode on a TPU raises: the interpreter would run in place of the chip
+    and every timing and trace would describe it instead.
+    """
+    import jax
+
+    backend = jax.default_backend()
+    if interpret is None:
+        return backend == "cpu"
+    if interpret and backend == "tpu":
+        raise ValueError(
+            "Pallas interpret mode was requested on a TPU backend; leave "
+            "interpret unset so the kernels compile for the chip")
+    return bool(interpret)
+
+
 @dataclasses.dataclass(frozen=True)
 class MiragePolicy:
     """Numerics policy applied to every dense GEMM in the model zoo.
@@ -77,7 +97,9 @@ class MiragePolicy:
         is value-identical to "float32" while halving bytes and doubling MXU
         throughput on TPU.
       use_pallas: route the fast path through the fused Pallas kernel.
-      interpret: run Pallas kernels in interpret mode (CPU container).
+      interpret: run Pallas kernels in interpret mode. ``None`` (default)
+        follows the platform: interpreted on CPU, compiled on TPU. ``True``
+        on a TPU raises (see :func:`resolve_interpret`).
       noise_sigma: analog phase-noise sigma (residue-level), Section VII.
         Honoured by backends with ``supports_noise``; requires an explicit
         PRNG key through ``mirage_matmul_nograd(..., key=...)`` or a
@@ -125,7 +147,7 @@ class MiragePolicy:
     rounding: str = "nearest"
     compute_dtype: str = "float32"
     use_pallas: bool = False
-    interpret: bool = True
+    interpret: Optional[bool] = None
     noise_sigma: float = 0.0
     snr_db: Optional[float] = None
     phase_drift_sigma: float = 0.0
@@ -156,6 +178,8 @@ class MiragePolicy:
             raise ValueError(f"rounding {self.rounding!r} not in {ROUNDING_MODES}")
         if self.mode.startswith("mirage"):
             check_overflow_bound(self.b_m, self.g, self.moduli)
+        if self.interpret:
+            resolve_interpret(True)
 
     @property
     def moduli(self) -> Tuple[int, int, int]:

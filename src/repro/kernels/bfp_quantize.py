@@ -12,10 +12,14 @@ field, so the kernel is bit-exact against the pure-jnp reference.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.precision import resolve_interpret
 
 
 def _exp2_int(e: jax.Array) -> jax.Array:
@@ -31,19 +35,68 @@ def _floor_log2(x: jax.Array) -> jax.Array:
     return ((bits >> 23) & 0xFF) - 127
 
 
-def _quantize_block(x: jax.Array, b_m: int, g: int, rounding: str) -> jax.Array:
-    """Fake-quantize a (rows, cols) block; cols must be a multiple of g."""
-    rows, cols = x.shape
-    xg = x.reshape(rows, cols // g, g)
-    maxabs = jnp.max(jnp.abs(xg), axis=-1, keepdims=True)
+def _group_max(a: jax.Array, g: int, axis: int) -> jax.Array:
+    """Max of each aligned run of ``g`` elements along ``axis``, broadcast
+    back to every element of its run.
+
+    A butterfly over the power-of-two ``g``: at stride ``s`` every element
+    takes the max with its partner at index ``i ^ s``, fetched with a
+    circular roll. Partners never leave their run (runs are ``g``-aligned
+    and ``g`` divides the axis), so the rolls' wrap-around is never read.
+    Rolls and selects keep the block in its native (sublane, lane) layout,
+    which Mosaic can lower; a ``(..., n // g, g)`` reshape puts ``g``
+    elements on the 128-wide lane axis and it cannot. The rolled index
+    vector picks the partner, so the result does not depend on the roll's
+    direction convention. Max is exact, so this equals the reshape-and-
+    reduce oracle bit for bit.
+    """
+    n = a.shape[axis]
+    idx = jax.lax.broadcasted_iota(jnp.int32, a.shape, axis)
+    s = 1
+    while s < g:
+        fwd = pltpu.roll(a, s, axis)
+        bwd = pltpu.roll(a, n - s, axis)
+        from_fwd = pltpu.roll(idx, s, axis) == (idx ^ s)
+        a = jnp.maximum(a, jnp.where(from_fwd, fwd, bwd))
+        s *= 2
+    return a
+
+
+def _quantize_block(x: jax.Array, b_m: int, g: int, rounding: str,
+                    axis: int = 1) -> jax.Array:
+    """Fake-quantize a 2-D block in groups of ``g`` along ``axis``; that
+    axis must be a multiple of the power-of-two ``g``."""
+    maxabs = _group_max(jnp.abs(x), g, axis)
     e = _floor_log2(jnp.maximum(maxabs, 1e-30))
     e = jnp.where(maxabs > 0, e, 0)
     scale = _exp2_int(e - (b_m - 1))
     qmax = float(2**b_m - 1)
-    v = xg / scale
+    v = x / scale
     q = jnp.trunc(v) if rounding == "truncate" else jnp.round(v)
     q = jnp.clip(q, -qmax, qmax)
-    return (q * scale).reshape(rows, cols)
+    return q * scale
+
+
+def check_group(g: int) -> None:
+    if g < 1 or g & (g - 1):
+        raise ValueError(f"the BFP Pallas kernels need a power-of-two "
+                         f"group size, got g={g}")
+
+
+def lane_multiple(g: int) -> int:
+    """Smallest block width that holds whole groups on whole 128-lane rows."""
+    return max(128, g)
+
+
+def round_up(n: int, m: int) -> int:
+    return n + (-n) % m
+
+
+def block_size(n: int, want: int, align: int) -> int:
+    """Block length along an axis of length ``n``: the whole axis when it
+    fits in ``want``, else ``want`` rounded down to a multiple of ``align``
+    (at least ``align``), as Mosaic's (8, 128) tiling requires."""
+    return n if n <= want else max(align, want // align * align)
 
 
 def _kernel(x_ref, o_ref, *, b_m: int, g: int, rounding: str):
@@ -59,29 +112,28 @@ def bfp_fake_quant_pallas(
     rounding: str = "nearest",
     block_rows: int = 256,
     block_cols: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Fake-quantize ``x`` along its last axis in BFP(b_m, g).
 
     Works for any rank: leading dims are flattened into rows. The last axis is
-    padded to a multiple of g (padding never leaks into group maxima because
-    padded lanes are zero and |x| >= 0 dominates them only within their own
-    padded group, which is discarded).
+    padded to whole 128-lane rows (padding never leaks into group maxima:
+    padded lanes are zero and fill only their own groups, which are
+    discarded). ``interpret=None`` follows the platform
+    (:func:`repro.core.precision.resolve_interpret`).
     """
+    check_group(g)
+    interpret = resolve_interpret(interpret)
     orig_shape = x.shape
     k = orig_shape[-1]
     xf = x.reshape(-1, k).astype(jnp.float32)
     rows = xf.shape[0]
-    pad_k = (-k) % g
-    if pad_k:
-        xf = jnp.pad(xf, ((0, 0), (0, pad_k)))
-    kp = k + pad_k
-
-    br = min(block_rows, rows)
-    bc = min(block_cols, kp)
-    bc = max(g, (bc // g) * g)  # block must contain whole groups
-    pad_r = (-rows) % br
-    pad_c = (-kp) % bc
+    lane = lane_multiple(g)
+    kp = round_up(k, lane)
+    br = block_size(rows, block_rows, 8)
+    bc = block_size(kp, block_cols, lane)
+    pad_r = round_up(rows, br) - rows
+    pad_c = round_up(kp, bc) - k
     if pad_r or pad_c:
         xf = jnp.pad(xf, ((0, pad_r), (0, pad_c)))
 

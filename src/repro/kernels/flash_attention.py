@@ -14,7 +14,8 @@ materialized). Causal and sliding-window masks are applied from absolute
 block offsets. Block shapes default to MXU-aligned (128, 128).
 
 Validated against ref.py / the pure-jnp chunked reference in interpret mode
-(tests/test_flash_kernel.py); on real TPU hardware pass interpret=False.
+(tests/test_flash_kernel.py); ``interpret=None`` follows the platform
+(interpreted on CPU, compiled on TPU).
 """
 
 from __future__ import annotations
@@ -27,6 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.precision import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -86,8 +89,9 @@ def flash_attention(
     window: Optional[int] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
+    interpret = resolve_interpret(interpret)
     B, Lq, H, D = q.shape
     S, Kv = k.shape[1], k.shape[2]
     assert H % Kv == 0

@@ -17,12 +17,15 @@ and contain whole BFP groups (block_k % g == 0).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.bfp_quantize import _quantize_block
+from repro.core.precision import resolve_interpret
+from repro.kernels.bfp_quantize import (_quantize_block, block_size,
+                                        check_group, lane_multiple, round_up)
 
 
 def _kernel(x_ref, w_ref, o_ref, *, b_m: int, g: int, rounding: str,
@@ -32,10 +35,9 @@ def _kernel(x_ref, w_ref, o_ref, *, b_m: int, g: int, rounding: str,
         o_ref[...] = jnp.zeros_like(o_ref)
 
     xq = _quantize_block(x_ref[...].astype(jnp.float32), b_m, g, rounding)
-    # weights: contraction dim is axis 0 -> quantize along columns of w^T
-    wq = _quantize_block(
-        w_ref[...].astype(jnp.float32).T, b_m, g, rounding
-    ).T
+    # weights: the contraction dim is axis 0, so groups run down sublanes
+    wq = _quantize_block(w_ref[...].astype(jnp.float32), b_m, g, rounding,
+                         axis=0)
     o_ref[...] += jnp.dot(
         xq.astype(compute_dtype), wq.astype(compute_dtype),
         preferred_element_type=jnp.float32,
@@ -57,9 +59,14 @@ def mirage_gemm_pallas(
     block_n: int = 128,
     block_k: int = 512,
     compute_dtype: str = "float32",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """``x @ w`` with fused BFP(b_m, g) quantization. x: (..., K), w: (K, N)."""
+    """``x @ w`` with fused BFP(b_m, g) quantization. x: (..., K), w: (K, N).
+
+    ``interpret=None`` follows the platform
+    (:func:`repro.core.precision.resolve_interpret`)."""
+    check_group(g)
+    interpret = resolve_interpret(interpret)
     orig_shape = x.shape
     K = orig_shape[-1]
     N = w.shape[1]
@@ -67,15 +74,21 @@ def mirage_gemm_pallas(
     xf = x.reshape(-1, K).astype(jnp.float32)
     M = xf.shape[0]
 
-    bm_ = min(block_m, max(M, 8))
-    bn = min(block_n, max(N, 8))
-    bk = min(block_k, K + (-K) % g)
-    bk = max(g, (bk // g) * g)
+    # K blocks are lanes of the x tile and sublanes of the w tile: whole
+    # groups on whole 128-lane rows
+    kp = round_up(K, lane_multiple(g))
+    bm_ = block_size(M, block_m, 8)
+    bn = block_size(N, block_n, 128)
+    bk = block_size(kp, block_k, lane_multiple(g))
 
-    pm, pn, pk = (-M) % bm_, (-N) % bn, (-K) % bk
+    pm = round_up(M, bm_) - M
+    pn = round_up(N, bn) - N
+    pk = round_up(kp, bk) - K
     if pm or pk:
         xf = jnp.pad(xf, ((0, pm), (0, pk)))
-    wf = jnp.pad(w.astype(jnp.float32), ((0, pk), (0, pn))) if (pk or pn) else w.astype(jnp.float32)
+    wf = w.astype(jnp.float32)
+    if pk or pn:
+        wf = jnp.pad(wf, ((0, pk), (0, pn)))
 
     grid = (xf.shape[0] // bm_, wf.shape[1] // bn, xf.shape[1] // bk)
     cdt = jnp.bfloat16 if compute_dtype == "bfloat16" else jnp.float32

@@ -1,14 +1,15 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On the CPU container all kernels execute with ``interpret=True`` (the policy
-default); on real TPU hardware set ``MiragePolicy(use_pallas=True,
-interpret=False)``. Each wrapper handles padding/reshaping so callers can pass
-arbitrary ranks; the kernels see MXU-aligned 2-D blocks.
+``policy.interpret`` (default ``None``) follows the platform: the kernels
+run interpreted on CPU and compiled on TPU, and interpret mode on a TPU is
+an error (``repro.core.precision.resolve_interpret``). Each wrapper handles
+padding/reshaping so callers can pass arbitrary ranks; the kernels see
+MXU-aligned 2-D blocks.
 """
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -36,19 +37,19 @@ def mirage_matmul_fused(x: jax.Array, w: jax.Array,
 
 def rns_residue_matmul(x_res: jax.Array, w_res: jax.Array,
                        moduli: Tuple[int, ...],
-                       interpret: bool = True) -> jax.Array:
+                       interpret: Optional[bool] = None) -> jax.Array:
     return rns_matmul_pallas(x_res, w_res, tuple(moduli), interpret=interpret)
 
 
 def rns_group_matmul(x_res: jax.Array, w_res: jax.Array,
                      moduli: Tuple[int, ...],
-                     interpret: bool = True) -> jax.Array:
+                     interpret: Optional[bool] = None) -> jax.Array:
     """Group-batched residue GEMM through the Pallas kernel.
 
     x_res: (n_mod, G, M, g), w_res: (n_mod, G, g, N) -> (n_mod, G, M, N).
 
-    The kernel's grid is modulus-major with the modulus value streamed in as
-    a (1,)-blocked operand, so one compiled kernel serves any number of
+    The kernel's grid is modulus-major with the modulus values scalar-
+    prefetched into SMEM, so one compiled kernel serves any number of
     "moduli" — flattening the (modulus, group) axes into n_mod * G slots
     with each modulus repeated G times executes ALL per-group modular GEMMs
     in a single pallas_call.
@@ -66,7 +67,7 @@ def rns_group_matmul_channel(x_res: jax.Array, w_res: jax.Array,
                              moduli: Tuple[int, ...],
                              noise: jax.Array,
                              adc_bits=None,
-                             interpret: bool = True) -> jax.Array:
+                             interpret: Optional[bool] = None) -> jax.Array:
     """Group-batched residue GEMM with the readout channel fused in-kernel.
 
     Same (modulus, group)-flattened grid as :func:`rns_group_matmul`, but

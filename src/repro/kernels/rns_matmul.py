@@ -7,9 +7,9 @@ per K-block (kept below the f32 exact-integer window 2^24) and reduces
 preserves the paper's invariant that no stored value ever exceeds
 ceil(log2 m) bits of information outside the accumulator.
 
-Grid: (modulus, M blocks, N blocks, K blocks). The modulus value is streamed
-in as a (1,)-blocked operand indexed by the first grid axis, so one compiled
-kernel serves the whole moduli set.
+Grid: (modulus, M blocks, N blocks, K blocks). The modulus values are
+scalar-prefetched into SMEM and indexed by the first grid axis, so one
+compiled kernel serves the whole moduli set.
 
 ``rns_matmul_pallas_channel`` is the analog-channel variant: the readout
 side of the channel (SNR-parameterized detector noise + ADC re-gridding,
@@ -31,10 +31,24 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.precision import resolve_interpret
+from repro.kernels.bfp_quantize import block_size, round_up
+
+
+def _blocks(M: int, K: int, N: int, moduli, block_m: int, block_n: int,
+            block_k: int):
+    """(8, 128)-aligned blocks; K blocks also keep every block-partial dot
+    exactly representable in f32 (block_k * (m-1)^2 < 2^24)."""
+    exact_cap = (2**24) // max(1, (max(moduli) - 1) ** 2)
+    return (block_size(M, block_m, 8),
+            block_size(K, min(block_k, exact_cap), min(128, exact_cap)),
+            block_size(N, block_n, 128))
 
 
 def _kernel(mod_ref, x_ref, w_ref, o_ref):
-    m = mod_ref[0]
+    m = mod_ref[pl.program_id(0)]
 
     @pl.when(pl.program_id(3) == 0)
     def _init():
@@ -56,13 +70,14 @@ def rns_matmul_pallas(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """(n_mod, M, K) x (n_mod, K, N) -> (n_mod, M, N) residue matmul.
 
     x_res/w_res: non-negative residues (int32 or exact f32).
     moduli: static tuple of modulus values.
     """
+    interpret = resolve_interpret(interpret)
     nm, M, K = x_res.shape
     N = w_res.shape[2]
     assert len(moduli) == nm, (moduli, x_res.shape)
@@ -70,13 +85,9 @@ def rns_matmul_pallas(
     wf = w_res.astype(jnp.float32)
     mf = jnp.asarray(moduli, jnp.float32)
 
-    # keep block-partial dots exactly representable in f32
-    max_m = max(moduli)
-    exact_cap = (2**24) // max(1, (max_m - 1) ** 2)
-    bk = max(1, min(block_k, K, exact_cap))
-    bm_ = min(block_m, M)
-    bn = min(block_n, N)
-    pm, pn, pk = (-M) % bm_, (-N) % bn, (-K) % bk
+    bm_, bk, bn = _blocks(M, K, N, moduli, block_m, block_n, block_k)
+    pm, pk, pn = (round_up(M, bm_) - M, round_up(K, bk) - K,
+                  round_up(N, bn) - N)
     if pm or pk:
         xf = jnp.pad(xf, ((0, 0), (0, pm), (0, pk)))
     if pk or pn:
@@ -85,13 +96,16 @@ def rns_matmul_pallas(
     grid = (nm, xf.shape[1] // bm_, wf.shape[2] // bn, xf.shape[2] // bk)
     out = pl.pallas_call(
         _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda mi, i, j, k: (mi,)),
-            pl.BlockSpec((1, bm_, bk), lambda mi, i, j, k: (mi, i, k)),
-            pl.BlockSpec((1, bk, bn), lambda mi, i, j, k: (mi, k, j)),
-        ],
-        out_specs=pl.BlockSpec((1, bm_, bn), lambda mi, i, j, k: (mi, i, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, bm_, bk), lambda mi, i, j, k, _: (mi, i, k)),
+                pl.BlockSpec((1, bk, bn), lambda mi, i, j, k, _: (mi, k, j)),
+            ],
+            out_specs=pl.BlockSpec((1, bm_, bn),
+                                   lambda mi, i, j, k, _: (mi, i, j)),
+        ),
         out_shape=jax.ShapeDtypeStruct((nm, xf.shape[1], wf.shape[2]),
                                        jnp.float32),
         interpret=interpret,
@@ -101,7 +115,9 @@ def rns_matmul_pallas(
 
 def _kernel_channel(mod_ref, step_ref, x_ref, w_ref, nz_ref, o_ref, *,
                     nk: int):
-    m = mod_ref[0]
+    m = mod_ref[pl.program_id(0)]
+    step = step_ref[pl.program_id(0)]
+    last_k = pl.program_id(3) == nk - 1
 
     @pl.when(pl.program_id(3) == 0)
     def _init():
@@ -110,14 +126,13 @@ def _kernel_channel(mod_ref, step_ref, x_ref, w_ref, nz_ref, o_ref, *,
     part = jnp.dot(x_ref[0], w_ref[0], preferred_element_type=jnp.float32)
     o_ref[0] = jnp.mod(o_ref[0] + jnp.mod(part, m), m)
 
-    @pl.when(pl.program_id(3) == nk - 1)
+    @pl.when(last_k)
     def _readout():
         # residue-level readout channel, fused on the VMEM-resident block:
         # detector phase noise (pre-scaled N(0, sigma_m) levels), nearest-
         # level re-quantize, ring wrap, then ADC re-grid. Bit-identical to
         # channel.phase_noise + channel.converter_quantize on the same draws.
         o = jnp.mod(jnp.round(o_ref[0] + nz_ref[0]), m)
-        step = step_ref[0]
         safe = jnp.where(step > 0, step, 1.0)
         oq = jnp.clip(jnp.round(jnp.round(o / safe) * safe), 0, m - 1)
         o_ref[0] = jnp.where(step > 0, oq, o)
@@ -137,7 +152,7 @@ def rns_matmul_pallas_channel(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Residue matmul with the readout channel fused into the epilogue.
 
@@ -147,6 +162,7 @@ def rns_matmul_pallas_channel(
       caller so determinism/keying stays outside the kernel.
     adc_bits: ADC precision; identity whenever ``2^bits >= m`` (per slot).
     """
+    interpret = resolve_interpret(interpret)
     nm, M, K = x_res.shape
     N = w_res.shape[2]
     assert len(moduli) == nm, (moduli, x_res.shape)
@@ -164,12 +180,9 @@ def rns_matmul_pallas_channel(
             steps.append((m - 1) / (2 ** adc_bits - 1))
     sf = jnp.asarray(steps, jnp.float32)
 
-    max_m = max(moduli)
-    exact_cap = (2**24) // max(1, (max_m - 1) ** 2)
-    bk = max(1, min(block_k, K, exact_cap))
-    bm_ = min(block_m, M)
-    bn = min(block_n, N)
-    pm, pn, pk = (-M) % bm_, (-N) % bn, (-K) % bk
+    bm_, bk, bn = _blocks(M, K, N, moduli, block_m, block_n, block_k)
+    pm, pk, pn = (round_up(M, bm_) - M, round_up(K, bk) - K,
+                  round_up(N, bn) - N)
     if pm or pk:
         xf = jnp.pad(xf, ((0, 0), (0, pm), (0, pk)))
     if pk or pn:
@@ -181,15 +194,20 @@ def rns_matmul_pallas_channel(
     grid = (nm, xf.shape[1] // bm_, wf.shape[2] // bn, nk)
     out = pl.pallas_call(
         functools.partial(_kernel_channel, nk=nk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda mi, i, j, k: (mi,)),
-            pl.BlockSpec((1,), lambda mi, i, j, k: (mi,)),
-            pl.BlockSpec((1, bm_, bk), lambda mi, i, j, k: (mi, i, k)),
-            pl.BlockSpec((1, bk, bn), lambda mi, i, j, k: (mi, k, j)),
-            pl.BlockSpec((1, bm_, bn), lambda mi, i, j, k: (mi, i, j)),
-        ],
-        out_specs=pl.BlockSpec((1, bm_, bn), lambda mi, i, j, k: (mi, i, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, bm_, bk),
+                             lambda mi, i, j, k, *_: (mi, i, k)),
+                pl.BlockSpec((1, bk, bn),
+                             lambda mi, i, j, k, *_: (mi, k, j)),
+                pl.BlockSpec((1, bm_, bn),
+                             lambda mi, i, j, k, *_: (mi, i, j)),
+            ],
+            out_specs=pl.BlockSpec((1, bm_, bn),
+                                   lambda mi, i, j, k, *_: (mi, i, j)),
+        ),
         out_shape=jax.ShapeDtypeStruct((nm, xf.shape[1], wf.shape[2]),
                                        jnp.float32),
         interpret=interpret,

@@ -9,9 +9,10 @@ refs — the per-subset reconstruction, the congruence checks, the binomial
 vote lookup and the winner select all fuse into one VMEM-resident pass per
 (block, subset) step. No ``(S, ...)`` intermediate ever exists.
 
-Per-subset constants stream in as ``(1, ...)``-blocked operand rows indexed
-by the subset grid axis (the same trick ``rns_matmul`` uses for the modulus
-value), so ONE compiled kernel serves any (moduli, n_required, psi) table.
+Per-subset constants are scalar-prefetched into SMEM and indexed by the
+subset grid axis (as ``rns_matmul`` does for the modulus value), so ONE
+compiled kernel serves any (moduli, n_required, psi) table. Elements are
+laid out as (rows, 128) tiles, so every residue row is whole vregs.
 
 The kernel runs entirely in f32 and therefore requires ``tables.f32_exact``
 (every reconstruction sum inside the exact-integer window 2^24 — always
@@ -22,20 +23,28 @@ must use the jnp fallback decode.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.precision import resolve_interpret
+from repro.kernels.bfp_quantize import block_size, round_up
 from repro.obs import health as obs_health
+
+
+_LANES = 128
 
 
 def _decode_kernel(wrow_ref, sub_ref, minv_ref, res_ref, dec_ref, vot_ref,
                    *, n_total: int, n_required: int, psi: float,
                    binom: Tuple[int, ...]):
-    @pl.when(pl.program_id(1) == 0)
+    s = pl.program_id(1)
+
+    @pl.when(s == 0)
     def _init():
         dec_ref[...] = jnp.zeros_like(dec_ref)
         vot_ref[...] = jnp.full_like(vot_ref, -2.0)
@@ -44,12 +53,12 @@ def _decode_kernel(wrow_ref, sub_ref, minv_ref, res_ref, dec_ref, vot_ref,
     # contraction over n_total positions equals the member sum exactly
     acc = None
     for i in range(n_total):
-        term = res_ref[i] * wrow_ref[0, i]
+        term = res_ref[i] * wrow_ref[s * n_total + i]
         acc = term if acc is None else acc + term
-    M_s = sub_ref[0, 0]
-    inv_M = sub_ref[0, 1]
-    psi_s = sub_ref[0, 2]
-    lo = sub_ref[0, 3]
+    M_s = sub_ref[4 * s]
+    inv_M = sub_ref[4 * s + 1]
+    psi_s = sub_ref[4 * s + 2]
+    lo = sub_ref[4 * s + 3]
     # round-based signed fold into [psi_s + 1 - M_s, psi_s] (two selects
     # absorb the half-up boundary and the reciprocal off-by-one)
     q = jnp.floor(acc * inv_M + 0.5)
@@ -62,8 +71,8 @@ def _decode_kernel(wrow_ref, sub_ref, minv_ref, res_ref, dec_ref, vot_ref,
     cons = None
     for i in range(n_total):
         d = X - res_ref[i]
-        k = jnp.round(d * minv_ref[1, i])
-        ok = (d - k * minv_ref[0, i] == 0.0).astype(jnp.float32)
+        k = jnp.round(d * minv_ref[n_total + i])
+        ok = (d - k * minv_ref[i] == 0.0).astype(jnp.float32)
         cons = ok if cons is None else cons + ok
     votes = jnp.full(X.shape, float(binom[0]))
     for e in range(1, n_total - n_required + 1):
@@ -81,42 +90,48 @@ def _decode_flat(res_flat: jax.Array, tables, block_e: int,
                  interpret: bool) -> Tuple[jax.Array, jax.Array]:
     n_total, E = res_flat.shape
     S = tables.n_subsets
-    be = min(block_e, max(E, 1))
-    pad = (-E) % be
+    rows = round_up(max(E, 1), _LANES) // _LANES
+    br = block_size(rows, block_e // _LANES, 8)
+    pad = round_up(rows, br) * _LANES - E
     if pad:
         res_flat = jnp.pad(res_flat, ((0, 0), (0, pad)))
-    wrow = jnp.asarray(tables.weights, jnp.float32)            # (S, n_total)
-    sub = jnp.stack([
+    res_tiles = res_flat.astype(jnp.float32).reshape(n_total, -1, _LANES)
+    # per-subset tables, flattened for SMEM: weights (S * n_total), then
+    # (M_s, 1/M_s, psi_s, psi_s + 1 - M_s) per subset, then moduli and
+    # their reciprocals
+    wrow = jnp.asarray(tables.weights, jnp.float32).reshape(-1)
+    sub = jnp.asarray(np.stack([
         tables.subset_M.astype(np.float32),
         (1.0 / tables.subset_M).astype(np.float32),
         tables.subset_psi.astype(np.float32),
         (tables.subset_psi + 1 - tables.subset_M).astype(np.float32),
-    ], axis=1)                                                 # (S, 4)
+    ], axis=1).reshape(-1))
     moduli = np.asarray(tables.moduli, np.float32)
-    minv = jnp.asarray(np.stack([moduli, 1.0 / moduli]))       # (2, n_total)
-    grid = (res_flat.shape[1] // be, S)
+    minv = jnp.asarray(np.concatenate([moduli, 1.0 / moduli]))
+    n_rows = res_tiles.shape[1]
+    grid = (n_rows // br, S)
     dec, vot = pl.pallas_call(
         functools.partial(
             _decode_kernel, n_total=n_total,
             n_required=tables.n_required, psi=float(tables.psi),
             binom=tables.binom),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, n_total), lambda e, s: (s, 0)),
-            pl.BlockSpec((1, 4), lambda e, s: (s, 0)),
-            pl.BlockSpec((2, n_total), lambda e, s: (0, 0)),
-            pl.BlockSpec((n_total, be), lambda e, s: (0, e)),
-        ],
-        out_specs=[
-            pl.BlockSpec((be,), lambda e, s: (e,)),
-            pl.BlockSpec((be,), lambda e, s: (e,)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=grid,
+            in_specs=[pl.BlockSpec((n_total, br, _LANES),
+                                   lambda e, s, *_: (0, e, 0))],
+            out_specs=[
+                pl.BlockSpec((br, _LANES), lambda e, s, *_: (e, 0)),
+                pl.BlockSpec((br, _LANES), lambda e, s, *_: (e, 0)),
+            ],
+        ),
         out_shape=[
-            jax.ShapeDtypeStruct((res_flat.shape[1],), jnp.float32),
-            jax.ShapeDtypeStruct((res_flat.shape[1],), jnp.float32),
+            jax.ShapeDtypeStruct((n_rows, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((n_rows, _LANES), jnp.float32),
         ],
         interpret=interpret,
-    )(wrow, sub, minv, res_flat.astype(jnp.float32))
+    )(wrow, sub, minv, res_tiles)
+    dec, vot = dec.reshape(-1), vot.reshape(-1)
     dec, vot = dec[:E], vot[:E]
     any_legal = vot >= 0.0
     decoded = jnp.where(any_legal, dec, 0.0).astype(jnp.int32)
@@ -138,7 +153,8 @@ def _decode_flat(res_flat: jax.Array, tables, block_e: int,
 
 
 def rrns_decode_pallas(residues: jax.Array, tables, block_e: int = 4096,
-                       interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+                       interpret: Optional[bool] = None,
+                       ) -> Tuple[jax.Array, jax.Array]:
     """RRNS majority decode through the subset-major Pallas kernel.
 
     residues: (n_total, ...) int32 over ``tables.moduli``; trailing dims are
@@ -152,6 +168,7 @@ def rrns_decode_pallas(residues: jax.Array, tables, block_e: int = 4096,
             "bound inside the 2^24 exact-integer window; this moduli set "
             f"({tables.moduli}) exceeds it — use the jnp rrns_decode, whose "
             "int32 fallback handles large moduli")
+    interpret = resolve_interpret(interpret)
     shape = residues.shape[1:]
     flat = residues.reshape(residues.shape[0], -1)
     decoded, corrected = _decode_flat(flat, tables, block_e, interpret)

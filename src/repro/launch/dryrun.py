@@ -28,7 +28,7 @@ from repro.configs.base import TrainConfig  # noqa: E402
 from repro.core.precision import get_policy  # noqa: E402
 from repro.launch import hlo_analysis as ha  # noqa: E402
 from repro.launch import roofline as rl  # noqa: E402
-from repro.launch.mesh import make_production_mesh  # noqa: E402
+from repro.launch.mesh import make_mesh, make_production_mesh  # noqa: E402
 from repro.models import build_model, input_specs  # noqa: E402
 from repro.models.lm import LMCallOptions  # noqa: E402
 from repro.parallel import sharding as sh  # noqa: E402
@@ -122,8 +122,7 @@ def lower_cell(arch_id: str, shape_name: str, multi_pod: bool,
         # but (data=32, model=8): FSDP all-gather wire per device is
         # (G-1)/G * N/tp and N/tp doubles DOWN as tp halves -> weight-gather
         # volume ~halves; TP all-reduce payload changes only by (7/8)/(15/16).
-        import jax as _jax
-        mesh = _jax.make_mesh((32, 8), ("data", "model"))
+        mesh = make_mesh((32, 8), ("data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     policy = policy_for(policy_name, shape, perf_level)

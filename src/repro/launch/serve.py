@@ -1,6 +1,9 @@
-"""Serving launcher: the continuous-batching engine on a reduced config.
+"""Serving launcher: the continuous-batching engine.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --requests 8
+
+Serves the model at its published widths by default; ``--reduced`` switches
+to the CPU-scale config (as in ``launch/train.py``).
 
 Knobs: ``--engine batched`` (one jitted decode over the stacked slot cache;
 default) vs ``--engine oracle`` (the retained per-slot parity loop);
@@ -51,6 +54,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core.precision import get_policy
+from repro.launch import compile_cache
 from repro.models import build_model
 from repro.models.lm import LMCallOptions
 from repro.obs import trace as obs_trace
@@ -58,9 +62,11 @@ from repro.obs.http import MetricsServer
 from repro.runtime.server import LMServer, PerSlotLMServer, Request
 
 
-def main(argv=None):
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
+    ap.add_argument("--reduced", action="store_true",
+                    help="reduced config (CPU-scale)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--max-tokens", type=int, default=12)
@@ -181,7 +187,6 @@ def main(argv=None):
         ap.error("--guardian snapshots at window boundaries; drop "
                  "--pipeline-depth")
 
-    mesh = None
     if args.mesh_data > 1 or args.mesh_model > 1:
         need = args.mesh_data * args.mesh_model
         if len(jax.devices()) < need:
@@ -190,11 +195,21 @@ def main(argv=None):
                 f"devices but only {len(jax.devices())} are visible; on a "
                 f"CPU box set "
                 f"XLA_FLAGS=--xla_force_host_platform_device_count={need}")
+    return args
+
+
+def build(args):
+    """The model, its random weights (seed 0) and the engine, as ``main``
+    serves them. Returns ``(cfg, server, injector)``."""
+    mesh = None
+    if args.mesh_data > 1 or args.mesh_model > 1:
         from repro.launch.mesh import make_debug_mesh
         mesh = make_debug_mesh(n_data=args.mesh_data,
                                n_model=args.mesh_model)
 
-    cfg = get_config(args.arch).reduced()
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
     overrides = {}
     if args.snr_db is not None:
         overrides.update(snr_db=args.snr_db, noise_seed=args.noise_seed)
@@ -239,6 +254,13 @@ def main(argv=None):
     else:
         server = PerSlotLMServer(model, params, cap=cap,
                                  batch_slots=args.slots)
+    return cfg, server, injector
+
+
+def main(argv=None):
+    compile_cache.enable()
+    args = parse_args(argv)
+    cfg, server, injector = build(args)
     if args.trace_export:
         obs_trace.configure(enabled=True)
     tracer = obs_trace.get_tracer()

@@ -192,7 +192,7 @@ def attn_apply(
         from repro.kernels.flash_attention import flash_attention
         out = flash_attention(
             q, k, v, causal=causal, window=window,
-            interpret=getattr(policy, "interpret", True))
+            interpret=policy.interpret)
     else:
         out = chunked_attention(
             q, k, v, positions, kv_pos,

@@ -208,7 +208,6 @@ def moe_apply_ep(p, x, policy: MiragePolicy, *, n_experts: int,
                  min_capacity: int = 4, opt=None):
     """shard_map expert-parallel MoE. Requires E % tp == 0 and an activation
     sharding plan (opt.act_dp/act_tp); falls back to moe_apply otherwise."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     tp = opt.axis_size(opt.act_tp) if (opt and opt.act_tp) else 1
@@ -243,11 +242,11 @@ def moe_apply_ep(p, x, policy: MiragePolicy, *, n_experts: int,
         with obs_health.suppressed():
             return fn(*args)
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         fn_no_health, mesh=mesh,
         in_specs=(P(dp, None), P(None, None), P(tp_ax, None, None),
                   P(tp_ax, None, None), P(tp_ax, None, None)),
         out_specs=(P(dp, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(xf, p["router"]["w"], p["gate"], p["up"], p["down"])
     return out.reshape(Bt, L, d), aux

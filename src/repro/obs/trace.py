@@ -87,11 +87,8 @@ class SpanTracer:
             self._load_annotation()
 
     def _load_annotation(self):
-        try:
-            from jax.profiler import TraceAnnotation
-            self._annotation = TraceAnnotation
-        except Exception:       # jax absent/old: spans still record
-            self._annotation = None
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
 
     def configure(self, enabled: Optional[bool] = None,
                   annotate: Optional[bool] = None) -> "SpanTracer":
@@ -207,27 +204,20 @@ def configure(enabled: Optional[bool] = None,
 def profile_window(logdir: str, tracer: Optional[SpanTracer] = None):
     """Capture a ``jax.profiler`` trace into ``logdir`` for the enclosed
     block, composing with the span tracer's annotations (spans appear as
-    named ranges inside the device timeline). Degrades to a warning when
-    the installed jax cannot start a profiler session."""
+    named ranges inside the device timeline). A profiler that cannot start
+    raises: a traced window must never silently hold no trace."""
     import jax
 
     prev = None
     if tracer is not None:
         prev = (tracer.enabled, tracer.annotate)
         tracer.configure(enabled=True, annotate=True)
-    started = False
     try:
+        jax.profiler.start_trace(logdir)
         try:
-            jax.profiler.start_trace(logdir)
-            started = True
-        except Exception as e:  # pragma: no cover - env dependent
-            print(f"# profile window unavailable: {e}")
-        yield
+            yield
+        finally:
+            jax.profiler.stop_trace()
     finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception as e:  # pragma: no cover
-                print(f"# profile stop failed: {e}")
         if tracer is not None and prev is not None:
             tracer.configure(enabled=prev[0], annotate=prev[1])

@@ -208,7 +208,9 @@ def fault_tolerant_train_loop(model, train_cfg, state, data, n_steps: int,
     import jax as _jax
     from repro.runtime.trainer import make_train_step
 
-    step_fn = _jax.jit(make_train_step(model, train_cfg))
+    # the state is donated: save_async copies it to host before returning,
+    # so no checkpoint reads a buffer the next step has reused
+    step_fn = _jax.jit(make_train_step(model, train_cfg), donate_argnums=0)
     guard = guard or PreemptionGuard(install=False)
     straggler = straggler or StragglerMitigator()
     metrics = {}

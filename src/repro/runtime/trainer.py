@@ -155,41 +155,74 @@ class StepTimer:
         return slow
 
 
+def memory_summary(compiled) -> Dict[str, int]:
+    """Device bytes of a compiled program (``memory_analysis()``): its
+    arguments, outputs, the outputs aliased onto donated arguments, and
+    temporaries. ``peak`` = arguments + outputs - aliased + temporaries."""
+    ma = compiled.memory_analysis()
+    out = {k: int(getattr(ma, f"{k}_size_in_bytes"))
+           for k in ("argument", "output", "alias", "temp")}
+    out["peak"] = (out["argument"] + out["output"] - out["alias"]
+                   + out["temp"])
+    return out
+
+
 def train_loop(model, train_cfg: TrainConfig, state, data_iter, n_steps: int,
                checkpointer=None, ckpt_every: int = 0, log_every: int = 10,
-               log_fn=print, registry=None):
+               log_fn=print, registry=None, report: Optional[dict] = None):
     """Single-host training loop with checkpoint/restart + straggler hooks.
+
+    The step donates the train state (params + optimizer moments are
+    updated in place; the caller's ``state`` is consumed) and is compiled
+    ahead of the first step, whose device memory is logged before it runs.
 
     Observability: each phase of the loop opens a tracer span
     (``train.data_next`` / ``train.step`` / ``train.host_sync`` — free when
     the tracer is disabled) and step latency/count land in ``registry``
     (default: the process registry) as ``train_step_seconds`` /
-    ``train_steps_total``."""
+    ``train_steps_total``. ``report``, when given, receives
+    ``compile_seconds``, ``memory`` (:func:`memory_summary`) and per-step
+    ``losses`` and ``step_seconds`` (host wall-clock to the loss)."""
     reg = registry if registry is not None else obs_metrics.get_registry()
     h_step = reg.histogram("train_step_seconds",
                            "walltime per optimizer step (dispatch + sync)")
     c_steps = reg.counter("train_steps_total", "optimizer steps completed")
     g_slow = reg.gauge("train_slow_steps", "straggler-flagged steps so far")
     tr = obs_trace.get_tracer()
-    step_fn = jax.jit(make_train_step(model, train_cfg))
+    step_fn = jax.jit(make_train_step(model, train_cfg), donate_argnums=0)
+    report = report if report is not None else {}
+    report.update(losses=[], step_seconds=[])
     timer = StepTimer()
     metrics = {}
     for i in range(n_steps):
         with tr.span("train.data_next"):
             batch = next(data_iter)
+        if i == 0:
+            t0 = time.perf_counter()
+            step_fn = step_fn.lower(state, batch).compile()
+            report["compile_seconds"] = time.perf_counter() - t0
+            report["memory"] = memory_summary(step_fn)
+            if log_every:
+                gib = {k: f"{v / 2**30:.2f}" for k, v in
+                       report["memory"].items()}
+                log_fn(f"train step compiled in "
+                       f"{report['compile_seconds']:.1f}s (host wall-clock);"
+                       f" device memory GiB: {gib}")
         t0 = time.perf_counter()
         with tr.span("train.step", {"i": i}):
             state, metrics = step_fn(state, batch)
         with tr.span("train.host_sync"):
-            jax.block_until_ready(metrics["loss"])
+            loss = float(metrics["loss"])
         dt = time.perf_counter() - t0
+        report["losses"].append(loss)
+        report["step_seconds"].append(dt)
         slow = timer.record(dt)
         h_step.observe(dt)
         c_steps.inc()
         g_slow.set(timer.slow_steps)
         step = int(state["step"])
         if log_every and (i % log_every == 0 or i == n_steps - 1):
-            log_fn(f"step {step}: loss={float(metrics['loss']):.4f} "
+            log_fn(f"step {step}: loss={loss:.4f} "
                    f"ppl={float(metrics.get('ppl', 0)):.2f} "
                    f"gnorm={float(metrics['grad_norm']):.3f}"
                    + (" [SLOW STEP]" if slow else ""))

@@ -47,7 +47,8 @@ def test_collectives_counted_per_iteration():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch.hlo_analysis import analyze_hlo
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2), ("data", "model"))
         def f(x, w):
             def body(h, _):
                 return jnp.tanh(h @ w), None
