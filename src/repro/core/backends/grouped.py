@@ -85,13 +85,14 @@ def prepare_activations(
     weight side entirely.
     """
     batch = x.shape[:-1]
-    t = bfp.bfp_quantize(x, policy.b_m, policy.g, policy.rounding)
-    G, g = t.mantissa.shape[-2], t.mantissa.shape[-1]
-    M = 1
-    for d in batch:
-        M *= d
-    qx = jnp.moveaxis(t.mantissa.reshape((M, G, g)), 1, 0)        # (G, M, g)
-    sx = jnp.moveaxis(t.scale.reshape((M, G, 1)), 1, 0)           # (G, M, 1)
+    with jax.named_scope("quantize_x"):
+        t = bfp.bfp_quantize(x, policy.b_m, policy.g, policy.rounding)
+        G, g = t.mantissa.shape[-2], t.mantissa.shape[-1]
+        M = 1
+        for d in batch:
+            M *= d
+        qx = jnp.moveaxis(t.mantissa.reshape((M, G, g)), 1, 0)    # (G, M, g)
+        sx = jnp.moveaxis(t.scale.reshape((M, G, 1)), 1, 0)       # (G, M, 1)
     return qx, sx, batch
 
 
@@ -112,11 +113,12 @@ def prepare_operands(
     less work per call.
     """
     qx, sx, batch = prepare_activations(x, policy)
-    if policy.assume_quantized_weights:
-        qw, sw = bfp.bfp_decompose_contract(w, policy.b_m, policy.g)
-    else:
-        qw, sw = bfp.bfp_quantize_contract(w, policy.b_m, policy.g,
-                                           policy.rounding)       # (G, g, N)
+    with jax.named_scope("quantize_w"):
+        if policy.assume_quantized_weights:
+            qw, sw = bfp.bfp_decompose_contract(w, policy.b_m, policy.g)
+        else:
+            qw, sw = bfp.bfp_quantize_contract(w, policy.b_m, policy.g,
+                                               policy.rounding)   # (G, g, N)
     return qx, sx, qw, sw, batch
 
 

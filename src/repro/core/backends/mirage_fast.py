@@ -17,9 +17,10 @@ from repro.core.backends.base import register_fn
 
 def _fold_x(x, policy):
     """Quantize-and-fold activations along the contraction dim -> (..., Kpad)."""
-    t = bfp.bfp_quantize(x, policy.b_m, policy.g, policy.rounding)
-    xg = t.mantissa * t.scale
-    return xg.reshape(xg.shape[:-2] + (xg.shape[-2] * xg.shape[-1],))
+    with jax.named_scope("quantize_x"):
+        t = bfp.bfp_quantize(x, policy.b_m, policy.g, policy.rounding)
+        xg = t.mantissa * t.scale
+        return xg.reshape(xg.shape[:-2] + (xg.shape[-2] * xg.shape[-1],))
 
 
 def _dot_dtype(policy):
@@ -41,17 +42,20 @@ def _matmul_mirage_fast(x, w, policy, *, key=None):
         return kops.mirage_matmul_fused(x, w, policy)
     dt = _dot_dtype(policy)
     xq = _fold_x(x, policy)                    # (..., Kpad)
-    if policy.assume_quantized_weights:
-        # weight operand already on the BFP grid (weight-stationary quant:
-        # quantized once per step, reused across microbatches/remat/transpose)
-        wq = w
-        if xq.shape[-1] != w.shape[0]:         # padding from x grouping
-            wq = jnp.pad(w, ((0, xq.shape[-1] - w.shape[0]), (0, 0)))
-    else:
-        qw, sw = bfp.bfp_quantize_contract(w, policy.b_m, policy.g,
-                                           policy.rounding)
-        wq = (qw * sw).reshape(-1, w.shape[-1])  # (Kpad, N)
-        if wq.shape[0] != xq.shape[-1]:
-            wq = wq[: xq.shape[-1]]
-    return jnp.matmul(xq.astype(dt), wq.astype(dt),
-                      preferred_element_type=jnp.float32)
+    with jax.named_scope("quantize_w"):
+        if policy.assume_quantized_weights:
+            # weight operand already on the BFP grid (weight-stationary
+            # quant: quantized once per step, reused across
+            # microbatches/remat/transpose)
+            wq = w
+            if xq.shape[-1] != w.shape[0]:     # padding from x grouping
+                wq = jnp.pad(w, ((0, xq.shape[-1] - w.shape[0]), (0, 0)))
+        else:
+            qw, sw = bfp.bfp_quantize_contract(w, policy.b_m, policy.g,
+                                               policy.rounding)
+            wq = (qw * sw).reshape(-1, w.shape[-1])  # (Kpad, N)
+            if wq.shape[0] != xq.shape[-1]:
+                wq = wq[: xq.shape[-1]]
+    with jax.named_scope("matmul"):
+        return jnp.matmul(xq.astype(dt), wq.astype(dt),
+                          preferred_element_type=jnp.float32)

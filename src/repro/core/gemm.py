@@ -44,7 +44,6 @@ import jax.numpy as jnp
 from repro.core import backends, bfp, stationary
 from repro.core.precision import MiragePolicy
 from repro.obs import health as obs_health
-from repro.obs import trace as obs_trace
 
 
 # --------------------------------------------------------------------------
@@ -143,12 +142,7 @@ def _forward_impl(x: jax.Array, w: jax.Array, policy: MiragePolicy,
             f"weight, or run an RNS-family mode")
     if key is None and backend.supports_noise:
         key = _ambient_subkey()
-    # span around the dispatch: inside jit this runs at TRACE time, so the
-    # host duration is compile/dispatch cost — the value is the
-    # jax.profiler.TraceAnnotation it opens when the tracer has
-    # annotate=True, which names the backend's device ops in a profiler
-    # capture (launch/serve.py --profile-window)
-    with obs_trace.get_tracer().span(f"gemm.{policy.mode}"):
+    with jax.named_scope("gemm"):
         return backend.forward(x, w, policy, key=key)
 
 
@@ -178,14 +172,16 @@ def _mm_bwd(policy, residuals, gout):
     if (policy.assume_quantized_weights
             and backends.resolve(policy).weight_stationary_aligned_only):
         dx_policy = policy.replace(assume_quantized_weights=False)
-    dx = _forward_impl(gout, w.T, dx_policy)
+    with jax.named_scope("gemm_dx"):
+        dx = _forward_impl(gout, w.T, dx_policy)
     # dW = X^T @ dO (contraction over tokens): neither operand is a
     # stationary weight -> always quantize both sides.
     dw_policy = (policy.replace(assume_quantized_weights=False)
                  if policy.assume_quantized_weights else policy)
-    xf = x.reshape(-1, x.shape[-1])            # (M, K)
-    gf = gout.reshape(-1, gout.shape[-1])      # (M, N)
-    dw = _forward_impl(xf.T, gf, dw_policy)    # (K, N)
+    with jax.named_scope("gemm_dw"):
+        xf = x.reshape(-1, x.shape[-1])            # (M, K)
+        gf = gout.reshape(-1, gout.shape[-1])      # (M, N)
+        dw = _forward_impl(xf.T, gf, dw_policy)    # (K, N)
     return dx.astype(x.dtype), dw.astype(w.dtype)
 
 

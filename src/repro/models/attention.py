@@ -163,24 +163,28 @@ def attn_apply(
     B, L, _ = x.shape
     src = x if x_kv is None else x_kv
     S = src.shape[1]
-    q = common.dense(p["q"], x, policy).reshape(B, L, n_heads, head_dim)
-    k = common.dense(p["k"], src, policy).reshape(B, S, n_kv_heads, head_dim)
-    v = common.dense(p["v"], src, policy).reshape(B, S, n_kv_heads, head_dim)
-    if qk_norm:
-        q = common.head_rmsnorm(p["q_norm"], q)
-        k = common.head_rmsnorm(p["k_norm"], k)
-    kv_pos = kv_positions if kv_positions is not None else (
-        positions if x_kv is None else jnp.arange(S))
-    if use_rope:
-        q = common.apply_rope(q, positions, rope_theta)
-        k = common.apply_rope(k, kv_pos, rope_theta)
-    k = _repeat_kv(k, kv_repeat)
-    v = _repeat_kv(v, kv_repeat)
-    # Pin head-parallel layout: batch over dp, heads over tp (replicated when
-    # the head count doesn't divide TP — never resharded mid-attention).
-    q = common.constrain(q, opt, ("dp", None, "tp", None))
-    k = common.constrain(k, opt, ("dp", None, "tp", None))
-    v = common.constrain(v, opt, ("dp", None, "tp", None))
+    with jax.named_scope("attn_qkv"):
+        q = common.dense(p["q"], x, policy).reshape(B, L, n_heads, head_dim)
+        k = common.dense(p["k"], src, policy).reshape(B, S, n_kv_heads,
+                                                      head_dim)
+        v = common.dense(p["v"], src, policy).reshape(B, S, n_kv_heads,
+                                                      head_dim)
+        if qk_norm:
+            q = common.head_rmsnorm(p["q_norm"], q)
+            k = common.head_rmsnorm(p["k_norm"], k)
+        kv_pos = kv_positions if kv_positions is not None else (
+            positions if x_kv is None else jnp.arange(S))
+        if use_rope:
+            q = common.apply_rope(q, positions, rope_theta)
+            k = common.apply_rope(k, kv_pos, rope_theta)
+        k = _repeat_kv(k, kv_repeat)
+        v = _repeat_kv(v, kv_repeat)
+        # Pin head-parallel layout: batch over dp, heads over tp (replicated
+        # when the head count doesn't divide TP — never resharded
+        # mid-attention).
+        q = common.constrain(q, opt, ("dp", None, "tp", None))
+        k = common.constrain(k, opt, ("dp", None, "tp", None))
+        v = common.constrain(v, opt, ("dp", None, "tp", None))
     score_dtype = (jnp.bfloat16 if opt is not None and
                    getattr(opt, "attn_dtype", "float32") == "bfloat16"
                    else jnp.float32)
@@ -188,21 +192,22 @@ def attn_apply(
     # self-attention (contiguous positions starting at 0) — train/prefill.
     use_flash = (opt is not None and getattr(opt, "use_flash_kernel", False)
                  and x_kv is None and kv_positions is None)
-    if use_flash:
-        from repro.kernels.flash_attention import flash_attention
-        out = flash_attention(
-            q, k, v, causal=causal, window=window,
-            interpret=policy.interpret)
-    else:
-        out = chunked_attention(
-            q, k, v, positions, kv_pos,
-            causal=causal, window=window, q_chunk=q_chunk, kv_chunk=kv_chunk,
-            score_dtype=score_dtype)
-    out = out.reshape(B, L, n_heads * head_dim)
-    out = common.constrain(out, opt, ("dp", None, "tp"))
-    if skip_o_proj:
-        return out, (k, v)
-    return common.dense(p["o"], out, policy), (k, v)
+    with jax.named_scope("attn_core"):
+        if use_flash:
+            from repro.kernels.flash_attention import flash_attention
+            out = flash_attention(
+                q, k, v, causal=causal, window=window,
+                interpret=policy.interpret)
+        else:
+            out = chunked_attention(
+                q, k, v, positions, kv_pos, causal=causal, window=window,
+                q_chunk=q_chunk, kv_chunk=kv_chunk, score_dtype=score_dtype)
+    with jax.named_scope("attn_out"):
+        out = out.reshape(B, L, n_heads * head_dim)
+        out = common.constrain(out, opt, ("dp", None, "tp"))
+        if skip_o_proj:
+            return out, (k, v)
+        return common.dense(p["o"], out, policy), (k, v)
 
 
 def attn_decode_step(

@@ -217,8 +217,9 @@ class LM:
         return h, n_prefix
 
     def _head(self, params, h):
-        h = common.norm(params["final_norm"], h, self.cfg.norm_eps,
-                        self.cfg.norm_type)
+        with jax.named_scope("final_norm"):
+            h = common.norm(params["final_norm"], h, self.cfg.norm_eps,
+                            self.cfg.norm_type)
         if self.cfg.tie_embeddings:
             return common.unembed(params["embed"], h, self.policy)
         return common.dense(params["lm_head"], h, self.policy)
@@ -232,7 +233,8 @@ class LM:
         hd = cfg.resolved_head_dim
         parallel = cfg.arch_id.startswith("command-r")
         merge = parallel and opt.merge_parallel_proj
-        n1 = common.norm(lp["ln1"], h, cfg.norm_eps, cfg.norm_type)
+        with jax.named_scope("attn_norm"):
+            n1 = common.norm(lp["ln1"], h, cfg.norm_eps, cfg.norm_type)
         a, _ = attention.attn_apply(
             lp["attn"], n1, policy, n_heads=cfg.n_heads,
             n_kv_heads=cfg.n_kv_heads, head_dim=hd, positions=positions,
@@ -241,15 +243,18 @@ class LM:
             q_chunk=opt.q_chunk, kv_chunk=opt.kv_chunk, opt=opt,
             skip_o_proj=merge)
         if self.kind == "attn_moe":
-            h = h + a
-            n2 = common.norm(lp["ln2"], h, cfg.norm_eps, cfg.norm_type)
+            with jax.named_scope("attn_out"):
+                h = h + a
+            with jax.named_scope("mlp_norm"):
+                n2 = common.norm(lp["ln2"], h, cfg.norm_eps, cfg.norm_type)
             moe_fn = (moe.moe_apply_ep if opt.moe_impl == "ep_shard_map"
                       else moe.moe_apply)
-            m, aux_l = moe_fn(
-                lp["moe"], n2, policy, n_experts=cfg.n_experts,
-                experts_per_token=cfg.experts_per_token,
-                capacity_factor=cfg.capacity_factor, opt=self.opt)
-            return h + m, aux + aux_l
+            with jax.named_scope("mlp"):
+                m, aux_l = moe_fn(
+                    lp["moe"], n2, policy, n_experts=cfg.n_experts,
+                    experts_per_token=cfg.experts_per_token,
+                    capacity_factor=cfg.capacity_factor, opt=self.opt)
+                return h + m, aux + aux_l
         # command-r parallel block: attn and mlp both read ln1(h)
         if parallel:
             if merge:
@@ -265,11 +270,15 @@ class LM:
                     [lp["attn"]["o"]["w"], lp["mlp"]["down"]["w"]], axis=0)
                 from repro.core.gemm import mirage_matmul_auto
                 return h + mirage_matmul_auto(cat, w_cat, policy), aux
-            m = common.mlp(lp["mlp"], n1, policy, opt=self.opt)
-            return h + a + m, aux
-        h = h + a
-        n2 = common.norm(lp["ln2"], h, cfg.norm_eps, cfg.norm_type)
-        return h + common.mlp(lp["mlp"], n2, policy, opt=self.opt), aux
+            with jax.named_scope("mlp"):
+                m = common.mlp(lp["mlp"], n1, policy, opt=self.opt)
+                return h + a + m, aux
+        with jax.named_scope("attn_out"):
+            h = h + a
+        with jax.named_scope("mlp_norm"):
+            n2 = common.norm(lp["ln2"], h, cfg.norm_eps, cfg.norm_type)
+        with jax.named_scope("mlp"):
+            return h + common.mlp(lp["mlp"], n2, policy, opt=self.opt), aux
 
     def _mamba_block(self, lp, h):
         cfg = self.cfg
@@ -319,10 +328,10 @@ class LM:
     def forward_hidden(self, params, tokens, extra_embeds=None):
         """Run the layer stack; returns (hidden, aux, n_prefix)."""
         cfg = self.cfg
-        h, n_prefix = self._embed_inputs(params, tokens, extra_embeds)
-        h = h.astype(self.opt.carry)
-        L = h.shape[1]
-        positions = jnp.arange(L)
+        with jax.named_scope("embed"):
+            h, n_prefix = self._embed_inputs(params, tokens, extra_embeds)
+            h = h.astype(self.opt.carry)
+            positions = jnp.arange(h.shape[1])
         emb0 = h
         aux0 = jnp.zeros((), jnp.float32)
 
@@ -344,9 +353,10 @@ class LM:
         body = _layer_noise_scoped(body)
         if self.opt.remat:
             body = jax.checkpoint(body, prevent_cse=False)
-        (h, aux), _ = obs_health.lifting_scan(
-            body, (h, aux0),
-            (params["layers"], jnp.arange(cfg.n_layers)))
+        with jax.named_scope("layers"):
+            (h, aux), _ = obs_health.lifting_scan(
+                body, (h, aux0),
+                (params["layers"], jnp.arange(cfg.n_layers)))
         return h, aux, n_prefix
 
     def forward(self, params, tokens, extra_embeds=None):
@@ -359,20 +369,24 @@ class LM:
         labels = batch["labels"]
         h, aux, n_prefix = self.forward_hidden(
             params, tokens, batch.get("patches"))
-        h = h[:, n_prefix:, :]
-        B, L, d = h.shape
-        if self.opt.ce_chunk:
-            head_fn = lambda hh: self._head(params, hh)
-            ce = chunked_ce(h.reshape(B * L, d), labels.reshape(B * L),
-                            head_fn, self.opt.ce_chunk)
-        else:
-            logits = self._head(params, h)
-            logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-            ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-            ce = -jnp.mean(ll)
-        total = ce + self.cfg.router_aux_loss * aux / max(self.cfg.n_layers, 1)
-        return total, {"ce": ce, "aux": aux,
-                       "ppl": jnp.exp(jnp.minimum(ce, 20.0))}
+        with jax.named_scope("head_ce"):
+            h = h[:, n_prefix:, :]
+            B, L, d = h.shape
+            if self.opt.ce_chunk:
+                head_fn = lambda hh: self._head(params, hh)
+                ce = chunked_ce(h.reshape(B * L, d), labels.reshape(B * L),
+                                head_fn, self.opt.ce_chunk)
+            else:
+                logits = self._head(params, h)
+                logp = jax.nn.log_softmax(logits.astype(jnp.float32),
+                                          axis=-1)
+                ll = jnp.take_along_axis(logp, labels[..., None],
+                                         axis=-1)[..., 0]
+                ce = -jnp.mean(ll)
+            total = ce + (self.cfg.router_aux_loss * aux
+                          / max(self.cfg.n_layers, 1))
+            return total, {"ce": ce, "aux": aux,
+                           "ppl": jnp.exp(jnp.minimum(ce, 20.0))}
 
     # ------------------------------------------------------------------
     # serving: prefill + single-token decode with caches

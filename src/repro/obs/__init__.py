@@ -8,7 +8,8 @@ Three planes, one package:
     metrics are registry-backed.
   * :mod:`repro.obs.trace` — thread-safe ring-buffer span tracer
     (~zero cost disabled), Chrome-trace/Perfetto export, composes with
-    ``jax.profiler`` via named annotations.
+    ``jax.profiler`` via named annotations; ``op_scopes`` maps a compiled
+    program's instructions to their ``jax.named_scope`` paths.
   * :mod:`repro.obs.health` — device-side accumulators for RRNS
     corrected/uncorrected residue faults and per-channel noise-stage
     activations, fetched with one host transfer per snapshot.

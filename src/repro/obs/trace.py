@@ -1,14 +1,13 @@
-"""Span tracer: a thread-safe ring buffer of timed spans, ~zero cost off.
+"""Span tracer: a thread-safe ring buffer of timed spans, ~zero cost off,
+and the map from a compiled program's device operations to named scopes.
 
 The serving tick pipeline (admit → prefill chunk → draft → verify/decode →
-host sync), the bucketed prefill, trainer steps and the GEMM backend
-dispatch are all instrumented with :meth:`SpanTracer.span`. Design:
+host sync), the bucketed prefill and trainer steps are instrumented with
+:meth:`SpanTracer.span`. Design:
 
   * **disabled is the default and costs one attribute check**: ``span()``
     on a disabled tracer returns a shared no-op context manager — no
-    generator frame, no clock read, no allocation. The <2% instrumented-on
-    overhead gate in ``benchmarks/bench_serving.py`` covers the ENABLED
-    path; the disabled path is unmeasurable.
+    generator frame, no clock read, no allocation.
   * **bounded memory**: spans land in a preallocated ring buffer
     (``capacity`` spans, default 64k); wraparound keeps the most recent
     spans. A long soak never grows the tracer.
@@ -21,10 +20,19 @@ dispatch are all instrumented with :meth:`SpanTracer.span`. Design:
     opens a ``jax.profiler.TraceAnnotation``, so host spans line up with
     XLA device activity inside a profiler capture window
     (``launch/serve.py --profile-window`` wraps N ticks in
-    ``jax.profiler.trace``). Note that a span around code traced inside
-    ``jax.jit`` measures TRACE time on first call and ~dispatch time after
-    — device-side truth comes from the profiler capture, which is exactly
-    why the two compose.
+    ``jax.profiler.trace``). Spans are host-side only: code traced inside
+    ``jax.jit`` runs once, at trace time, so a span there names no device
+    operation.
+
+Device-side names come from ``jax.named_scope``: the GEMM backend's phases
+(``gemm``, ``gemm_dx``, ``gemm_dw``, ``quantize_x``, ``quantize_w``,
+``matmul``, ``prequantize``), the model's sub-layers (``embed``,
+``layers``, ``attn_norm``, ``attn_qkv``, ``attn_core``, ``attn_out``,
+``mlp_norm``, ``mlp``, ``final_norm``, ``head_ce``) and the train step's
+``clip`` and ``optimizer``. A scope goes into each HLO instruction's
+``op_name`` metadata at no run-time cost; :func:`op_scopes` reads it back
+from the compiled program, keyed by the instruction names a profiler trace
+gives its device operations.
 """
 
 from __future__ import annotations
@@ -32,6 +40,7 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import re
 import threading
 import time
 from typing import Dict, List, Optional
@@ -221,3 +230,59 @@ def profile_window(logdir: str, tracer: Optional[SpanTracer] = None):
     finally:
         if tracer is not None and prev is not None:
             tracer.configure(enabled=prev[0], annotate=prev[1])
+
+
+_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_CALLED = re.compile(r"\b(?:body|condition|to_apply)=%?([\w.\-]+)"
+                     r"|\bbranch_computations=\{([^}]*)\}")
+
+
+def op_scopes(hlo_text: str) -> Dict[str, str]:
+    """``{instruction name: op_name}`` for every top-level instruction of an
+    optimized HLO module (``compiled.as_text()``): the entry computation,
+    while bodies and conditions, conditional branches, everything but the
+    computations a fusion calls. The ``op_name`` is the named-scope path
+    joined by ``/``, transforms wrapped round a scope's name
+    (``transpose(jvp(layers))``); a fusion carries its root's.
+
+    An instruction XLA inserted (a layout copy, a zero broadcast) has no
+    metadata: it takes the ``op_name`` of its first user in the computation
+    that has one, else that of the instruction calling its computation (a
+    while loop for its body), else ``""``."""
+    from repro.launch.hlo_analysis import parse_computations
+
+    comps = parse_computations(hlo_text)
+    fused, caller = set(), {}
+    for comp in comps.values():
+        for ins in comp.instrs:
+            m = _CALLS.search(ins.rhs) if ins.op == "fusion" else None
+            if m:
+                fused.add(m.group(1))
+                continue
+            for m in _CALLED.finditer(ins.rhs):
+                for callee in (m.group(1) or m.group(2)).split(","):
+                    caller.setdefault(callee.strip(" %"), ins.name)
+    out: Dict[str, str] = {}
+    for comp in comps.values():
+        if comp.name in fused:
+            continue
+        users: Dict[str, List[str]] = {}
+        for ins in comp.instrs:
+            for o in ins.operands:
+                users.setdefault(o, []).append(ins.name)
+        for ins in reversed(comp.instrs):   # users are printed after
+            m = _OP_NAME.search(ins.rhs)
+            out[ins.name] = m.group(1) if m else next(
+                (out[u] for u in users.get(ins.name, ()) if out[u]), "")
+    for _ in range(len(comps)):             # callers before callees
+        changed = False
+        for name, comp in comps.items():
+            up = out.get(caller.get(name, ""), "")
+            for ins in comp.instrs:
+                if up and not out.get(ins.name, "x"):
+                    out[ins.name] = up
+                    changed = True
+        if not changed:
+            break
+    return out

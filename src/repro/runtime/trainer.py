@@ -89,7 +89,8 @@ def make_train_step(model, train_cfg: TrainConfig,
         if wsq:
             # quantize once per step; grads flow straight-through to the FP32
             # master below (paper Eq. 4 semantics).
-            params = _prequantize_params(params, train_cfg.policy, qdtype)
+            with jax.named_scope("prequantize"):
+                params = _prequantize_params(params, train_cfg.policy, qdtype)
 
         if nmb > 1:
             def split(x):
@@ -119,16 +120,18 @@ def make_train_step(model, train_cfg: TrainConfig,
                 grads, state["err"], train_cfg.policy.b_m, train_cfg.policy.g)
 
         if train_cfg.grad_clip > 0:
-            grads, gnorm = clip_by_global_norm(grads, train_cfg.grad_clip)
+            with jax.named_scope("clip"):
+                grads, gnorm = clip_by_global_norm(grads, train_cfg.grad_clip)
         else:
             gnorm = jnp.zeros(())
 
-        lr = lr_schedule(state["step"])
-        # the optimizer always updates the FP32 MASTER weights (Eq. 4)
-        new_params, new_opt = opt_update(grads, state["opt"],
-                                         state["params"], lr)
-        new_state = dict(state, params=new_params, opt=new_opt,
-                         step=state["step"] + 1)
+        with jax.named_scope("optimizer"):
+            lr = lr_schedule(state["step"])
+            # the optimizer always updates the FP32 MASTER weights (Eq. 4)
+            new_params, new_opt = opt_update(grads, state["opt"],
+                                             state["params"], lr)
+            new_state = dict(state, params=new_params, opt=new_opt,
+                             step=state["step"] + 1)
         if train_cfg.grad_compression == "bfp":
             new_state["err"] = new_err
         metrics = dict(metrics, loss=loss, grad_norm=gnorm, lr=lr)

@@ -357,3 +357,143 @@ def test_spec_and_fold_contract():
     assert int(acc2["rrns_corrected"]) == 3
     assert int(acc2["rrns_uncorrected"]) == 0
     assert "not_in_spec" not in acc2            # spec is the contract
+
+
+# --------------------------------------------------------------------------
+# named scopes: the compiled train step's device work by program layer
+# --------------------------------------------------------------------------
+
+# the scopes the program opens (repro.obs.trace module docstring)
+SCOPES = {"gemm", "gemm_dx", "gemm_dw", "quantize_x", "quantize_w", "matmul",
+          "prequantize", "embed", "layers", "attn_norm", "attn_qkv",
+          "attn_core", "attn_out", "mlp_norm", "mlp", "final_norm", "head_ce",
+          "clip", "optimizer"}
+
+
+def _scopes_on(op_name):
+    """Scope names on an op_name path, transforms (jvp, transpose)
+    unwrapped; a jitted function's segment (``jit(clip)``) is no scope."""
+    found = set()
+    for seg in re.split(r"[/;]", op_name):
+        m = re.match(r"^(?:jvp|transpose|vmap)\((.*)\)$", seg)
+        while m:
+            seg = m.group(1)
+            m = re.match(r"^(?:jvp|transpose|vmap)\((.*)\)$", seg)
+        if seg in SCOPES:
+            found.add(seg)
+    return found
+
+
+@pytest.fixture(scope="module", params=["mirage", "bf16"])
+def step_hlo(request):
+    """The optimized HLO of the benchmark's train step at qwen2-0.5b's
+    published widths, cut to 2 layers and a 4,096-token vocabulary, batch
+    4 x 512, layer remat and 512-token CE chunks."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.configs.base import TrainConfig
+    from repro.models import build_model
+    from repro.models.lm import LMCallOptions
+    from repro.runtime.trainer import init_train_state, make_train_step
+
+    cfg = dataclasses.replace(get_config("qwen2-0.5b"), n_layers=2,
+                              vocab_size=4096, norm_eps=1e-6)
+    extra = {"b_m": 4, "g": 16} if request.param == "mirage" else {}
+    pol = get_policy(request.param, **extra)
+    model = build_model(cfg, pol, LMCallOptions(
+        remat=True, ce_chunk=512, q_chunk=64, kv_chunk=64))
+    tc = TrainConfig(policy=pol, optimizer="adamw", lr=1e-3, grad_clip=1.0)
+    state = jax.eval_shape(lambda k: init_train_state(model, tc, k),
+                           jax.random.PRNGKey(0))
+    batch = {k: jax.ShapeDtypeStruct((4, 512), jnp.int32)
+             for k in ("tokens", "labels")}
+    step = jax.jit(make_train_step(model, tc), donate_argnums=0)
+    return request.param, step.lower(state, batch).compile().as_text()
+
+
+def _top_level(hlo):
+    """Instructions of the entry computation and of every while body,
+    transitively: the operations a profiler trace shows."""
+    from repro.launch.hlo_analysis import parse_computations
+
+    comps = parse_computations(hlo)
+    entry = re.search(r"^ENTRY %?([\w.\-]+)", hlo, re.M).group(1)
+    todo, seen = [entry], set()
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for ins in comps[name].instrs:
+            if ins.op == "while":
+                todo.append(re.search(r"\bbody=%?([\w.\-]+)",
+                                      ins.rhs).group(1))
+    return [ins for n in seen for ins in comps[n].instrs]
+
+
+def test_op_scopes_name_every_gemm_phase(step_hlo):
+    from repro.obs.trace import op_scopes
+
+    mode, hlo = step_hlo
+    names = list(op_scopes(hlo).values())
+    for scope in ("attn_core", "optimizer", "clip", "layers", "head_ce"):
+        assert any(scope in _scopes_on(n) for n in names), scope
+    if mode != "mirage":    # a bf16 cast fuses into its user
+        return
+    quant = [n for n in names if _scopes_on(n) & {"quantize_x", "quantize_w"}]
+    forward = [n for n in quant if "transpose(" not in n]
+    remat = [n for n in quant if "rematted_computation" in n]
+    for phase in (forward, remat,
+                  [n for n in quant if "gemm_dx" in _scopes_on(n)],
+                  [n for n in quant if "gemm_dw" in _scopes_on(n)]):
+        for scope in ("quantize_x", "quantize_w"):
+            assert any(scope in _scopes_on(n) for n in phase), (
+                scope, len(phase))
+
+
+def test_every_top_level_fusion_and_matmul_is_scoped(step_hlo):
+    from repro.obs.trace import op_scopes
+
+    _, hlo = step_hlo
+    m = op_scopes(hlo)
+    top = _top_level(hlo)
+    const = {i.name for i in top if i.op == "constant"}
+    # a fill of constants reads no data: the zero JAX instantiates for the
+    # CE's transposed ``where`` carries no scope, and XLA on the TPU fuses
+    # it into its user
+    work = [i for i in top if i.op in ("fusion", "dot", "convolution")
+            and not set(i.operands) <= const]
+    assert len(work) > 20
+    bare = [(i.name, m.get(i.name)) for i in work
+            if not _scopes_on(m.get(i.name) or "")]
+    assert not bare, bare[:10]
+
+
+def test_op_scopes_reads_top_level_instructions_only():
+    from repro.obs.trace import op_scopes
+
+    def f(x):
+        with jax.named_scope("optimizer"):
+            y = jnp.sin(x) * 2 + 1
+
+        def body(c, _):
+            with jax.named_scope("attn_core"):
+                return c * 1.5 + jnp.cos(c), None
+        with jax.named_scope("layers"):
+            return jax.lax.scan(body, y, None, length=3)[0]
+
+    hlo = jax.jit(f).lower(jnp.ones(64)).compile().as_text()
+    m = op_scopes(hlo)
+    fused = set(re.findall(r"calls=%?([\w.\-]+)", hlo))
+    body = re.search(r"\bbody=%?([\w.\-]+)", hlo).group(1)
+    from repro.launch.hlo_analysis import parse_computations
+    comps = parse_computations(hlo)
+    for name in fused:
+        assert not any(i.name in m for i in comps[name].instrs)
+    assert all(i.name in m for i in comps[body].instrs)
+    assert any("attn_core" in _scopes_on(m[i.name])
+               for i in comps[body].instrs)
+    assert any("optimizer" in _scopes_on(n) for n in m.values())
+    # an instruction without metadata takes its user's or its caller's
+    assert all(m[i.name] for i in comps[body].instrs)
