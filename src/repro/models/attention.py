@@ -2,13 +2,17 @@
 
 Memory discipline: scores are never materialized at (L x S). The prefill path
 runs an online-softmax scan over KV chunks inside a map over Q chunks, so the
-peak buffer is (B, q_chunk, H, kv_chunk). Supports causal masking, sliding
-windows (mixtral), QK-norm (qwen3), cross-attention (seamless), and KV-head
-repetition so kv heads can be sharded over large TP meshes.
+peak buffer is (B, q_chunk, H, kv_chunk). The backward (a ``custom_vjp``)
+keeps the same bound: its residuals are q, k, v, the output and one
+log-sum-exp per query row, and it recomputes each block's scores. Supports
+causal masking, sliding windows (mixtral), QK-norm (qwen3), cross-attention
+(seamless), and KV-head repetition so kv heads can be sharded over large TP
+meshes.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple, Optional
 
@@ -76,14 +80,21 @@ def chunked_attention(
 ) -> jax.Array:
     """Online-softmax attention; returns (B, Lq, H, D).
 
+    Forward: a map over Q chunks of an online-softmax scan over KV chunks;
+    the peak buffer is one (B, q_chunk, H, kv_chunk) block of scores.
+    Backward (flash-style, ``jax.custom_vjp``): the residuals are q, k, v,
+    the output and each query row's log-sum-exp; every block's scores and
+    probabilities are recomputed from them, so nothing per KV step is kept.
+    Query rows with no valid key (the pad up to a whole chunk, at position
+    -1) take no gradient: their cotangent is zero.
+
     score_dtype=bfloat16 halves the HBM traffic of the materialized score/
     probability tensors (the dominant memory term of training cells — see
-    EXPERIMENTS.md §Perf); running max/denominator/accumulator stay f32."""
+    EXPERIMENTS.md §Perf); running max/denominator/accumulator stay f32.
+    The backward's products take the same operand dtype, f32 sums."""
     B, Lq, H, D = q.shape
     Sk, Kv = k.shape[1], k.shape[2]
-    rep = H // Kv
     assert H % Kv == 0, (H, Kv)
-    sm_scale = 1.0 / math.sqrt(D)
 
     qc = min(q_chunk, Lq)
     kc = min(kv_chunk, Sk)
@@ -96,7 +107,32 @@ def chunked_attention(
         k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
         k_positions = jnp.pad(k_positions, (0, pad_k), constant_values=2**30)
-    nq, nk = q.shape[1] // qc, k.shape[1] // kc
+    out = _blocked_attention(q, k, v, q_positions, k_positions, causal, window,
+                             qc, kc, score_dtype)
+    return out[:, :Lq]
+
+
+def _block_scores(qi, ki, qp, kp, causal, window, score_dtype):
+    """Scaled, masked scores of one (Q chunk, KV chunk) block:
+    (B, qc, Kv, rep, D) x (B, kc, Kv, D) -> (B, qc, Kv, rep, kc)."""
+    sm_scale = 1.0 / math.sqrt(qi.shape[-1])
+    s = jnp.einsum("bqkrd,bskd->bqkrs",
+                   qi.astype(score_dtype), ki.astype(score_dtype),
+                   preferred_element_type=score_dtype) * sm_scale
+    mask = _chunk_mask(qp, kp, causal, window)  # (qc, kc)
+    neg = jnp.asarray(-3e4 if score_dtype == jnp.bfloat16 else NEG_INF,
+                      score_dtype)
+    return jnp.where(mask[None, :, None, None, :], s, neg)
+
+
+def _attention_fwd(q, k, v, q_positions, k_positions, causal, window, qc, kc,
+                   score_dtype):
+    """Chunk-aligned forward: the output (B, Lq, H, D) and the row
+    log-sum-exp (nq, B, qc, Kv, rep) in f32."""
+    B, Lq, H, D = q.shape
+    Kv = k.shape[2]
+    rep = H // Kv
+    nq, nk = Lq // qc, k.shape[1] // kc
 
     # (B, nq, qc, Kv, rep, D) view of q; k/v chunked on axis 1.
     q5 = q.reshape(B, nq, qc, Kv, rep, D)
@@ -111,13 +147,7 @@ def chunked_attention(
         def kv_step(carry, inp):
             acc, m_run, l_run = carry
             ki, vi, kp = inp  # (B, kc, Kv, D), (B, kc, Kv, D), (kc,)
-            s = jnp.einsum("bqkrd,bskd->bqkrs",
-                           qi.astype(score_dtype), ki.astype(score_dtype),
-                           preferred_element_type=score_dtype) * sm_scale
-            mask = _chunk_mask(qp, kp, causal, window)  # (qc, kc)
-            neg = jnp.asarray(-3e4 if score_dtype == jnp.bfloat16 else NEG_INF,
-                              score_dtype)
-            s = jnp.where(mask[None, :, None, None, :], s, neg)
+            s = _block_scores(qi, ki, qp, kp, causal, window, score_dtype)
             m_new = jnp.maximum(m_run, jnp.max(s, axis=-1).astype(jnp.float32))
             p = jnp.exp(s - m_new[..., None].astype(score_dtype))
             alpha = jnp.exp(m_run - m_new)
@@ -134,11 +164,87 @@ def chunked_attention(
             kv_step, (acc0, m0, l0),
             (jnp.moveaxis(k4, 1, 0), jnp.moveaxis(v4, 1, 0), kpos))
         out = acc / jnp.maximum(l_run[..., None], 1e-30)
-        return out  # (B, qc, Kv, rep, D)
+        # (B, qc, Kv, rep, D), and the row log-sum-exp (B, qc, Kv, rep)
+        return out, m_run + jnp.log(l_run)
 
-    outs = jax.lax.map(one_q_chunk, (jnp.moveaxis(q5, 1, 0), qpos))
+    outs, lse = jax.lax.map(one_q_chunk, (jnp.moveaxis(q5, 1, 0), qpos))
     out = jnp.moveaxis(outs, 0, 1).reshape(B, nq * qc, H, D)
-    return out[:, :Lq]
+    return out, lse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _blocked_attention(q, k, v, q_positions, k_positions, causal, window, qc,
+                       kc, score_dtype):
+    return _attention_fwd(q, k, v, q_positions, k_positions, causal, window,
+                          qc, kc, score_dtype)[0]
+
+
+def _blocked_attention_fwd(q, k, v, q_positions, k_positions, causal, window,
+                           qc, kc, score_dtype):
+    out, lse = _attention_fwd(q, k, v, q_positions, k_positions, causal,
+                              window, qc, kc, score_dtype)
+    return out, (q, k, v, q_positions, k_positions, out, lse)
+
+
+def _blocked_attention_bwd(causal, window, qc, kc, score_dtype, res, g):
+    """Flash-attention backward over the forward's blocks. Per block:
+    p = exp(s - lse), dP = dO V^T, dS = p (dP - rowsum(dO O)); dQ sums
+    dS K over KV chunks (carried per Q chunk), dK and dV sum dS^T Q and
+    p^T dO over the rep query heads of each KV head and over Q chunks."""
+    q, k, v, q_positions, k_positions, out, lse = res
+    B, Lq, H, D = q.shape
+    Sk, Kv = k.shape[1], k.shape[2]
+    rep = H // Kv
+    nq, nk = Lq // qc, Sk // kc
+    sm_scale = 1.0 / math.sqrt(D)
+    sd = score_dtype
+
+    def q_major(x):  # (B, Lq, H, D) -> (nq, B, qc, Kv, rep, D)
+        return jnp.moveaxis(x.reshape(B, nq, qc, Kv, rep, D), 1, 0)
+
+    delta = jnp.sum(g * out, axis=-1)  # (B, Lq, H); out and g are f32
+    delta = jnp.moveaxis(delta.reshape(B, nq, qc, Kv, rep), 1, 0)
+    k4 = jnp.moveaxis(k.reshape(B, nk, kc, Kv, D), 1, 0)
+    v4 = jnp.moveaxis(v.reshape(B, nk, kc, Kv, D), 1, 0)
+    kpos = k_positions.reshape(nk, kc)
+
+    def q_step(carry, inp):
+        dk_acc, dv_acc = carry
+        qi, doi, lse_i, delta_i, qp = inp
+
+        def kv_step(dq_i, kin):
+            ki, vi, kp = kin
+            s = _block_scores(qi, ki, qp, kp, causal, window, sd)
+            p = jnp.exp(s - lse_i[..., None])  # f32
+            dp = jnp.einsum("bqkrd,bskd->bqkrs", doi.astype(sd),
+                            vi.astype(sd), preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_i[..., None])).astype(sd)
+            dq_i = dq_i + jnp.einsum("bqkrs,bskd->bqkrd", ds, ki.astype(sd),
+                                     preferred_element_type=jnp.float32)
+            dk_j = jnp.einsum("bqkrs,bqkrd->bskd", ds, qi.astype(sd),
+                              preferred_element_type=jnp.float32)
+            dv_j = jnp.einsum("bqkrs,bqkrd->bskd", p.astype(sd),
+                              doi.astype(sd),
+                              preferred_element_type=jnp.float32)
+            return dq_i, (dk_j, dv_j)
+
+        dq0 = jnp.zeros((B, qc, Kv, rep, D), jnp.float32)
+        dq_i, (dk_parts, dv_parts) = jax.lax.scan(kv_step, dq0,
+                                                  (k4, v4, kpos))
+        return (dk_acc + dk_parts, dv_acc + dv_parts), dq_i * sm_scale
+
+    zeros = jnp.zeros((nk, B, kc, Kv, D), jnp.float32)
+    (dk, dv), dq = jax.lax.scan(
+        q_step, (zeros, zeros),
+        (q_major(q), q_major(g), lse, delta, q_positions.reshape(nq, qc)))
+    dq = jnp.moveaxis(dq, 0, 1).reshape(B, Lq, H, D)
+    dk = jnp.moveaxis(dk * sm_scale, 0, 1).reshape(B, Sk, Kv, D)
+    dv = jnp.moveaxis(dv, 0, 1).reshape(B, Sk, Kv, D)
+    return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
+            None, None)
+
+
+_blocked_attention.defvjp(_blocked_attention_fwd, _blocked_attention_bwd)
 
 
 def attn_apply(

@@ -452,6 +452,94 @@ def test_op_scopes_name_every_gemm_phase(step_hlo):
                 scope, len(phase))
 
 
+# chunked attention in the fixture's step (B 4, 512 tokens, 64-token Q and
+# KV chunks, 14 heads on 2 KV heads of 64): every buffer that splits the
+# heads as (Kv, rep) = (2, 7), and the backward's per-KV-chunk dK/dV
+# partials (nk, B, kc, Kv, D)
+_ATTN_SHAPE = re.compile(r"\[(?:[\d,]*,)?2,7(?:,[\d,]*)?\]|\[8,4,64,2,64\]")
+# autodiff through the KV scan stacked a (B, qc, Kv, rep, D) carry for
+# every (Q chunk, KV chunk): (nq, nk, B, qc, Kv, rep, D)
+_OLD_RESIDUAL = re.compile(r"\b(\w+)\[8,8,4,64,2,7,64\]")
+_NOT_ATTENTION = {"optimizer", "clip", "quantize_x", "quantize_w",
+                  "prequantize"}
+# loop state and its plumbing: no device work of their own
+_PLUMBING = {"parameter", "get-tuple-element", "tuple", "constant", "while"}
+
+
+def _result_shape(ins):
+    return ins.rhs[:ins.rhs.find(f" {ins.op}(")]
+
+
+def _dims(ins):
+    m = re.match(r"\w+\[([\d,]+)\]", _result_shape(ins))
+    return sorted(map(int, m.group(1).split(","))) if m else None
+
+
+def _body(loop):
+    return re.search(r"\bbody=%?([\w.\-]+)", loop.rhs).group(1)
+
+
+def test_attention_backward_is_scoped_as_attention_core(step_hlo):
+    """The custom backward's instructions carry ``attn_core``, so the
+    benchmark counts them as attention (``chipbench/scopes.bucket``), and
+    none stacks per-block residuals of the old shape."""
+    from repro.launch.hlo_analysis import parse_computations
+    from repro.obs.trace import op_scopes
+
+    _, hlo = step_hlo
+    m = op_scopes(hlo)
+
+    def attention_core(name):
+        words = _scopes_on(m.get(name) or "")
+        return "attn_core" in words and not words & _NOT_ATTENTION
+
+    # a fill of constants reads no data (as in the test below); the forward
+    # that remat recomputes is left out, since XLA fuses its k/v chunking
+    # into the projection and names the fusion after remat's transpose
+    top = _top_level(hlo)
+    const = {i.name for i in top if i.op == "constant"}
+    shaped = [i for i in top if i.op not in _PLUMBING
+              and _ATTN_SHAPE.search(_result_shape(i))
+              and not set(i.operands) <= const
+              and "rematted_computation" not in (m.get(i.name) or "")]
+    assert sum("transpose(" in (m.get(i.name) or "") for i in shaped) > 10
+    bare = [(i.name, m.get(i.name)) for i in shaped
+            if not attention_core(i.name)]
+    assert not bare, bare[:10]
+
+    # the backward's loops, found by their dK and dV products (B, kc, Kv,
+    # D), which no forward computes: the KV-chunk loop and the Q-chunk loop
+    # around it; every instruction in them is attention
+    comps = parse_computations(hlo)
+    whiles = [i for c in comps.values() for i in c.instrs if i.op == "while"]
+    inner = {_body(w) for w in whiles if any(
+        i.op == "dot" and _dims(i) == [2, 4, 64, 64]
+        for i in comps[_body(w)].instrs)}
+    outer = {_body(w) for w in whiles if any(
+        i.op == "while" and _body(i) in inner
+        for i in comps[_body(w)].instrs)}
+    assert inner and outer
+    # (a loop counter's scalar copy is plumbing too)
+    work = [i for b in inner | outer for i in comps[b].instrs
+            if i.op not in _PLUMBING and not set(i.operands) <= const
+            and not re.match(r"\w+\[\]", _result_shape(i))]
+    assert sum(i.op == "dot" for i in work) >= 5
+    bare = [(i.name, m.get(i.name)) for i in work
+            if not attention_core(i.name)]
+    assert not bare, bare[:10]
+
+    # no float stack of per-block residuals anywhere; the forward's causal
+    # mask, a function of positions alone, is computed once outside the
+    # layer scan (as before the custom backward) and no backward reads it
+    old = {}
+    for line in hlo.splitlines():
+        for dtype in _OLD_RESIDUAL.findall(line):
+            old.setdefault(dtype, []).append(line.strip()[:200])
+    assert set(old) <= {"pred"}, {k: v[:3] for k, v in old.items()}
+    assert not any("transpose(" in line for line in old.get("pred", [])), (
+        old["pred"][:3])
+
+
 def test_every_top_level_fusion_and_matmul_is_scoped(step_hlo):
     from repro.obs.trace import op_scopes
 
