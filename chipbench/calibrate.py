@@ -78,9 +78,8 @@ def self_compare(cell, seeds, out, kind):
     import jax.numpy as jnp
 
     from chipbench import gen
-    from chipbench.reference import qwen2 as reference
 
-    conf, mix = cell.config, cell.traffic
+    conf, mix, reference = cell.config, cell.traffic, cell.reference
     other = dict(conf, attention_operands="fp32") if kind == "attention" \
         else conf
     compare = harness.load_module(cell.driver_path).compare

@@ -2,7 +2,9 @@
 
 Set-up builds one object, the trainer's compiled step (``make_train_step``,
 jitted with the state donated) with its state from a jitted init on the
-device, and drives it through the mix's first ``check_steps`` steps on
+device, checks that the program's parameter tree is the one the cell's
+reference reads from the configuration (leaf by leaf, name and shape),
+and drives it through the mix's first ``check_steps`` steps on
 distinct batches, each ending in the loss fetch as ``train_loop`` does.
 Those steps give the readings that are compared with the reference: each
 step's loss, each leaf's norm of the first gradient (recovered from
@@ -26,8 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import counts, gen, program, reduce
-from chipbench.reference import qwen2 as reference
+from chipbench import gen, program, reduce
+from chipbench.harness import BenchError
 
 
 def run(r) -> None:
@@ -35,7 +37,7 @@ def run(r) -> None:
     from repro.runtime.trainer import init_train_state, make_train_step
 
     t_enter = time.perf_counter()
-    conf, mix = r.cell.config, r.cell.traffic
+    conf, mix, reference = r.cell.config, r.cell.traffic, r.cell.reference
     policy = program.policy(r.program_policy or conf["policy"])
     model = program.model(conf, policy, mix)
     tc = TrainConfig(policy=policy, optimizer=mix["optimizer"],
@@ -49,6 +51,7 @@ def run(r) -> None:
     init = jax.jit(lambda k: init_train_state(model, tc, k))
     state = jax.block_until_ready(init(key))
     t_init = time.perf_counter()
+    check_tree(state["params"], reference.param_shapes(conf))
     batch = next(data)
     step = jax.jit(make_train_step(model, tc), donate_argnums=0).lower(
         state, batch).compile()
@@ -71,8 +74,8 @@ def run(r) -> None:
     g1 = {k: float(np.sqrt(np.sum(np.square(v, dtype=np.float64))))
           for k, v in g_host.items()}
     change = jax.device_get(change_norms(state["params"], key))
-    r.layer["flops_per_token"] = counts.train_flops_per_token(conf,
-                                                              mix["seq"])
+    r.layer["flops_per_token"] = reference.train_flops_per_token(conf,
+                                                                 mix["seq"])
     r.layer["setup_phases"] = phases = {
         "runtime_s": t_enter - r.t_start, "weights_s": t_init - t_enter,
         "step_program_s": t_compile - t_init,
@@ -159,6 +162,21 @@ def compare(losses, grad, change, ref):
     return {"loss_gap": loss_gap, "grad_gap": grad_gap,
             "update_gap": update_gap, "grad_diff": grad_diff,
             "final_norm_grad_diff": rd[head] / rg[head]}
+
+
+def check_tree(params, want) -> None:
+    """Refuse a program whose parameter tree differs from the reference's
+    ``{leaf name: shape}``, naming the first leaf that differs: a size the
+    program did not take from the configuration would be measured, and
+    compared, as the wrong model."""
+    got = {k: tuple(x.shape) for k, x in _by_name(params).items()}
+    want = {k: tuple(v) for k, v in want.items()}
+    for k in sorted(set(got) | set(want)):
+        if got.get(k) != want.get(k):
+            raise BenchError(
+                f"parameter {k}: the program has "
+                f"{got.get(k, 'no such leaf')}, the configuration states "
+                f"{want.get(k, 'no such leaf')}")
 
 
 def step_memory(compiled) -> dict:

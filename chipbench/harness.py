@@ -4,13 +4,34 @@ Everything that belongs to one configuration, traffic mix or per-layer
 metric is a file of its own, found by the name ``BENCHMARK.json`` gives:
 
 * ``configs/<config>.json``: the model's published sizes, its numerics
-  ``policy`` and the ``control_policy`` one precision below;
+  ``policy``, the ``control_policy`` one precision below, and the
+  ``reference`` that decides ``correct``; an optional ``cpu_copy`` gives
+  the keys the tests' CPU copy changes (``tests/tiny.py``);
+* ``reference/<reference>.py``: the plain reference of one architecture,
+  independent of the program, with
+
+  - ``train_readings(conf, policy, key, batches, lr, clip, b1, ce_chunk,
+    grad, keep_grad)``: the loss of each step, each leaf's norm of the
+    first gradient and of the parameters' change, and, given the
+    program's first gradient, each leaf's norm of the difference;
+  - ``leaf_norms(tree) -> {leaf name: norm}``;
+  - ``train_flops_per_token(conf, seq)``: the flops the forward and
+    backward passes need per trained token, which ``step_mfu`` and the
+    rooflines read;
+  - ``param_shapes(conf) -> {leaf name: shape}``: the parameter tree the
+    configuration states, which the program's tree has to match before
+    its first step;
 * ``traffic/<mix>.json``: the mix's parameters; its ``kind`` names the
-  driver ``drivers/<kind>.py`` that runs such a mix;
+  driver ``drivers/<kind>.py`` that runs such a mix; an optional
+  ``cpu_copy`` as for a configuration;
 * ``limits/<config>.<mix>.json``: the limit of each number that decides
   ``correct``, with the readings it was set from;
 * ``metrics/<metric>.py``: the reader of one per-layer metric, ``read(run)
   -> float | None``.
+
+A configuration of a new architecture thus brings its configuration,
+traffic, limits and reference files and its metric readers; the program
+builds the model from the file's keys (``program.py``).
 
 The result line has the keys ``correct``, ``attempted``, ``failed``,
 ``metrics``, ``device``, with ``--trace 1`` ``breakdown``, and last
@@ -68,6 +89,11 @@ class Cell:
         configs = {c["name"]: c for c in bench["configs"]}
         self.config_entry = configs[self.entry["config"]]
         self.config = _read_json(root / self.config_entry["file"])
+        ref = base / "reference" / f"{self.config.get('reference')}.py"
+        if not ref.is_file():
+            raise BenchError(f"no reference module {ref.name!r} for "
+                             f"configuration {self.entry['config']!r}")
+        self.reference = load_module(ref)
         self.traffic_name = self.entry["traffic"]
         self.traffic = _read_json(base / "traffic" / f"{self.traffic_name}.json")
         self.driver_path = HERE / "drivers" / f"{self.traffic['kind']}.py"

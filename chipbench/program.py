@@ -1,4 +1,12 @@
-"""The system under test, built as a configuration file states it."""
+"""The system under test, built as a configuration file states it.
+
+The program's ``repro.configs.from_published(conf)``, where it has one,
+builds the ``ModelConfig`` from the file's published ``config.json`` keys
+and its ``arch_id``; until then :data:`_KEYS` stands in for it. Either
+way the driver holds the model's parameter tree to the one the cell's
+reference reads from the same file, so a size the program leaves out
+stops the run.
+"""
 
 from __future__ import annotations
 
@@ -16,9 +24,12 @@ _KEYS = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
 def model_config(conf: Dict):
     """The program's ModelConfig for ``conf['arch_id']`` with every size the
     file gives in place of the program's own."""
-    from repro.configs import get_config
+    from repro import configs
 
-    base = get_config(conf["arch_id"])
+    reader = getattr(configs, "from_published", None)
+    if reader is not None:
+        return reader(conf)
+    base = configs.get_config(conf["arch_id"])
     return dataclasses.replace(base, **{
         v: conf[k] for k, v in _KEYS.items() if k in conf})
 
@@ -31,9 +42,11 @@ def policy(spec: Dict):
 
 
 def model(conf: Dict, pol, mix: Dict):
+    """The model with every traffic key that names an ``LMCallOptions``
+    field passed through."""
     from repro.models import build_model
     from repro.models.lm import LMCallOptions
 
-    opts = {k: mix[k] for k in ("q_chunk", "kv_chunk", "remat", "ce_chunk")
-            if k in mix}
+    opts = {f.name: mix[f.name] for f in dataclasses.fields(LMCallOptions)
+            if f.name in mix}
     return build_model(model_config(conf), pol, LMCallOptions(**opts))

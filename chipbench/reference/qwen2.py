@@ -29,6 +29,11 @@ norms, the softmax's exponent and sum and the division by it are float32.
 Weights are drawn from the seed by the configuration's initialisation
 recipe: N(0, 1/d_in) for projections, N(0, 0.02^2) for the embedding, unit
 norm scales and zero biases, one key split per layer.
+
+The harness reads a configuration naming ``"reference": "qwen2"`` through
+:func:`train_readings`, :func:`leaf_norms`, :func:`param_shapes` and
+:func:`train_flops_per_token` (the dense decoder's count in
+``chipbench/counts.py``).
 """
 
 from __future__ import annotations
@@ -39,6 +44,8 @@ from typing import Dict, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from chipbench import counts
 
 HI = jax.lax.Precision.HIGHEST
 
@@ -206,6 +213,22 @@ def init_params(s: Shape, key) -> Dict:
     }
 
 
+def _name(path) -> str:
+    return "/".join(str(getattr(k, "key", k)) for k in path)
+
+
+def param_shapes(conf: Dict) -> Dict[str, Tuple[int, ...]]:
+    """``{leaf name: shape}`` of the parameters ``conf`` states."""
+    tree = jax.eval_shape(functools.partial(init_params, Shape(conf)),
+                          jax.random.PRNGKey(0))
+    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
+    return {_name(path): x.shape for path, x in flat}
+
+
+def train_flops_per_token(conf: Dict, seq: int) -> float:
+    return counts.train_flops_per_token(conf, seq)
+
+
 # --------------------------------------------------------------------------
 # forward
 # --------------------------------------------------------------------------
@@ -294,7 +317,7 @@ def loss(s: Shape, rounding, params, tokens, labels, chunk: int = 512):
 
 def leaf_norms(tree) -> Dict[str, jax.Array]:
     flat, _ = jax.tree_util.tree_flatten_with_path(tree)
-    return {"/".join(str(getattr(k, "key", k)) for k in path):
+    return {_name(path):
             jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
             for path, x in flat}
 
@@ -350,8 +373,7 @@ def train_readings(conf: Dict, policy: Dict, key, batches: Sequence[Dict],
     arrays = None
     if keep_grad:
         flat, _ = jax.tree_util.tree_flatten_with_path(g1)
-        arrays = {"/".join(str(getattr(q, "key", q)) for q in path):
-                  jax.device_get(x) for path, x in flat}
+        arrays = {_name(path): jax.device_get(x) for path, x in flat}
     diff = None
     if grad is not None:
         # each leaf's norm of (program's gradient - reference's), one leaf
@@ -360,7 +382,7 @@ def train_readings(conf: Dict, policy: Dict, key, batches: Sequence[Dict],
         sub = jax.jit(lambda a, b: jnp.sqrt(jnp.sum(jnp.square(a - b))))
         diff = {}
         for path, x in flat:
-            k = "/".join(str(getattr(q, "key", q)) for q in path)
+            k = _name(path)
             diff[k] = float(sub(jnp.asarray(grad[k]), x))
     del g1
     # the starting weights are drawn again rather than kept alongside

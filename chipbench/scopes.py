@@ -21,6 +21,11 @@ bucket, by the first rule that matches the scope names on its path:
 
 A fusion takes one scope, so rounding that XLA fuses into a matmul's
 fusion counts as ``matmul``, not as ``gemm_quantize``.
+
+The names below are the program's: ``repro.obs.trace.SCOPE_NAMES`` where
+the program declares them, else the ones ``repro.obs.trace``'s docstring
+lists. :func:`ms_per_step_in` reads any scope by name, one a bucket rule
+names or not, so a metric of a scope a new model opens is a new file.
 """
 
 from __future__ import annotations
@@ -28,14 +33,18 @@ from __future__ import annotations
 import contextlib
 import re
 import time
-from typing import Dict, Optional
+from typing import Dict, Iterable, List, Optional, Tuple
+
+from repro.obs import trace as _trace
 
 QUANTIZE = frozenset({"quantize_x", "quantize_w", "prequantize"})
-# every scope the program opens (``repro.obs.trace``'s docstring)
-SCOPES = QUANTIZE | frozenset({
+# every scope the program opens, as ``repro.obs.trace``'s docstring lists
+# them, for a program that does not declare its own
+_LISTED = QUANTIZE | frozenset({
     "gemm", "gemm_dx", "gemm_dw", "matmul", "embed", "layers", "attn_norm",
     "attn_qkv", "attn_core", "attn_out", "mlp_norm", "mlp", "final_norm",
     "head_ce", "clip", "optimizer"})
+SCOPES = frozenset(getattr(_trace, "SCOPE_NAMES", _LISTED))
 BUCKETS = ("optimizer", "gemm_quantize", "attention_core", "layer_scan",
            "other_scoped", "unscoped")
 # a transform wraps the scope it applies to: transpose(jvp(layers))
@@ -46,8 +55,8 @@ _TRANSFORM = re.compile(r"^(?:jvp|transpose|vmap)\((.*)\)$")
 _KEY_ON_METADATA = "jax_compilation_cache_include_metadata_in_key"
 
 
-def scopes_on(op_name: str) -> set:
-    """The scope names on an ``op_name`` path (``/``-separated; XLA joins
+def segments(op_name: str) -> set:
+    """The segments of an ``op_name`` path (``/``-separated; XLA joins
     merged instructions' names with ``;``), transforms unwrapped. A jitted
     function's segment (``jit(clip)``) is a function, not a scope."""
     found = set()
@@ -56,9 +65,13 @@ def scopes_on(op_name: str) -> set:
         while m:
             seg = m.group(1)
             m = _TRANSFORM.match(seg)
-        if seg in SCOPES:
-            found.add(seg)
+        found.add(seg)
     return found
+
+
+def scopes_on(op_name: str) -> set:
+    """The scope names of :data:`SCOPES` on an ``op_name`` path."""
+    return segments(op_name) & SCOPES
 
 
 def bucket(op_name: str) -> str:
@@ -168,25 +181,39 @@ def scope_map(run) -> Optional[Dict]:
     return run.layer["op_scopes"]
 
 
-def split(run) -> Optional[Dict[str, float]]:
-    """Seconds of device self time per step in each of :data:`BUCKETS`, and
-    ``missing``; ``None`` where the trace, the step count or the map is
-    absent, or where no operation carries a scope named here."""
+def _ops(run) -> Optional[Tuple[List[Tuple[Optional[str], float]], int]]:
+    """``([(op_name, seconds), ...], steps)`` of the traced window, the
+    ``op_name`` ``None`` for an instruction the map lacks; ``None`` where
+    the trace, the step count or the map is absent."""
     t, steps = run.layer.get("trace"), run.layer.get("steps")
     if not t or not steps:
         return None
     m = scope_map(run)
     if not m:
         return None
-    out = dict.fromkeys(BUCKETS + ("missing",), 0.0)
+    ops = []
     for key, op in t["ops"].items():
         prog, _, ins = key.rpartition("/")
-        name = m["scopes"].get(ins) if prog == m["program"] else None
+        ops.append((m["scopes"].get(ins) if prog == m["program"] else None,
+                    op["s"]))
+    return ops, steps
+
+
+def split(run) -> Optional[Dict[str, float]]:
+    """Seconds of device self time per step in each of :data:`BUCKETS`, and
+    ``missing``; ``None`` where the trace, the step count or the map is
+    absent, or where no operation carries a scope named here."""
+    got = _ops(run)
+    if got is None:
+        return None
+    ops, steps = got
+    out = dict.fromkeys(BUCKETS + ("missing",), 0.0)
+    for name, s in ops:
         if name is None:
-            out["unscoped"] += op["s"]
-            out["missing"] += op["s"]
+            out["unscoped"] += s
+            out["missing"] += s
         else:
-            out[bucket(name)] += op["s"]
+            out[bucket(name)] += s
     if not any(out[b] for b in BUCKETS[:-1]):
         return None
     return {k: v / steps for k, v in out.items()}
@@ -196,3 +223,16 @@ def ms_per_step(run, name: str) -> Optional[float]:
     """One bucket of :func:`split` in milliseconds per step."""
     s = split(run)
     return None if s is None else 1e3 * s[name]
+
+
+def ms_per_step_in(run, names: Iterable[str]) -> Optional[float]:
+    """Device self time per step, in milliseconds, of the operations whose
+    scope path holds any of ``names``; ``None`` where the trace, the step
+    count or the map is absent, or where no operation is under them."""
+    got = _ops(run)
+    if got is None:
+        return None
+    ops, steps = got
+    names = set(names)
+    hit = [s for name, s in ops if name and segments(name) & names]
+    return 1e3 * sum(hit) / steps if hit else None

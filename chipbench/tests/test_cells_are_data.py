@@ -37,6 +37,18 @@ def test_no_code_names_a_cell():
             assert name not in text, f"{path.name} names {name}"
 
 
+def test_no_code_imports_a_reference():
+    """A cell's reference is found by the name its configuration gives."""
+    refs = {p.stem for p in (harness.HERE / "reference").glob("*.py")}
+    for path in harness.HERE.rglob("*.py"):
+        if {"tests", "reference"} & set(path.parts):
+            continue
+        text = path.read_text()
+        assert "reference import" not in text, path.name
+        for ref in refs:
+            assert f"reference.{ref}" not in text, f"{path.name} names {ref}"
+
+
 def test_cells_differ_only_in_data():
     """The bf16 training cell is the mirage one with another config file,
     and the two files differ only in their numerics."""

@@ -6,6 +6,7 @@ bucket.
 """
 
 import os
+import re
 import subprocess
 import sys
 import types
@@ -117,6 +118,51 @@ def test_readers_return_none_without_a_map_or_trace(bucket):
     run.layer["op_scopes"]["scopes"] = dict.fromkeys(
         run.layer["op_scopes"]["scopes"], f"{J}/add")
     assert reader.read(run) is None
+
+
+def test_scopes_are_the_programs():
+    """``SCOPES`` is the set the program declares, or, where it declares
+    none, the set its ``repro.obs.trace`` docstring lists; either way it
+    holds every name the program's source opens."""
+    from repro.obs import trace
+
+    src = harness.ROOT / "src" / "repro"
+    opened = {m for p in src.rglob("*.py") for m in re.findall(
+        r"named_scope\(\s*[\"'](\w+)[\"']", p.read_text())}
+    listed = set(re.findall(r"``(\w+)``", trace.__doc__.split(
+        "Device-side names come from")[1].split("A scope goes")[0]))
+    declared = getattr(trace, "SCOPE_NAMES", None)
+    assert scopes.SCOPES == (listed if declared is None else set(declared))
+    assert opened <= scopes.SCOPES
+
+
+@pytest.mark.parametrize("names,bucket", [
+    (["attn_core"], "attention_core"),
+    (["optimizer", "clip"], "optimizer"),
+    (sorted(scopes.QUANTIZE), "gemm_quantize"),
+])
+def test_ms_per_step_in_reads_as_the_bucket_it_coincides_with(names, bucket):
+    run = _run()
+    assert scopes.ms_per_step_in(run, names) == pytest.approx(
+        scopes.ms_per_step(run, bucket))
+
+
+def test_ms_per_step_in_reads_a_scope_no_rule_names():
+    """A scope a new model opens is read by name, whether or not a bucket
+    rule or ``SCOPES`` names it."""
+    run = _run()
+    run.layer["trace"]["ops"]["jit_train_step/fusion.20"] = {
+        "s": 0.006, "n": STEPS, "class": "other"}
+    run.layer["op_scopes"]["scopes"]["fusion.20"] = \
+        f"{LAYER}/router/dot_general"
+    assert scopes.ms_per_step_in(run, ["router"]) == pytest.approx(
+        1e3 * 0.006 / STEPS)
+
+
+def test_ms_per_step_in_returns_none_with_nothing_to_read():
+    assert scopes.ms_per_step_in(_run(), ["router"]) is None
+    assert scopes.ms_per_step_in(_run(scope_map=False), ["attn_core"]) is None
+    assert scopes.ms_per_step_in(_run(steps=0), ["attn_core"]) is None
 
 
 def test_align_keys_the_scopes_by_the_names_that_ran():

@@ -1,17 +1,20 @@
 """A copy of the benchmark's cells at a size a CPU test can hold.
 
 Every cell of BENCHMARK.json is rebuilt in a temporary directory with the
-same traffic, policies and limits, at the configuration's published widths
-but 2 layers and a 4,096-token vocabulary. The CPU runs a float32 dot in
-full float32, not as the TPU's one bfloat16 pass, so the copy states its
-attention products as float32. The harness runs it as it would on the
-chip, minus the look for a chip.
+same traffic, policies, limits and references. A configuration or traffic
+file's ``cpu_copy`` gives the keys its copy changes; without one, a
+configuration keeps its published widths at 2 layers and a 4,096-token
+vocabulary (:data:`TINY`), and a mix traces for 1 s (:data:`TRAIN`). The
+CPU runs a float32 dot in full float32, not as the TPU's one bfloat16
+pass, so the default copy states its attention products as float32. The
+harness runs it as it would on the chip, minus the look for a chip.
 """
 
 from __future__ import annotations
 
 import copy
 import json
+import shutil
 from pathlib import Path
 
 from chipbench import harness
@@ -26,15 +29,18 @@ def bench_copy(tmp: Path, limits=None) -> dict:
     bench = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
     for sub in ("configs", "traffic", "limits"):
         (tmp / sub).mkdir(parents=True, exist_ok=True)
+    shutil.copytree(harness.HERE / "reference", tmp / "reference",
+                    ignore=shutil.ignore_patterns("__pycache__"),
+                    dirs_exist_ok=True)
     for c in bench["configs"]:
         conf = json.loads((harness.ROOT / c["file"]).read_text())
-        conf.update(TINY)
+        conf.update(conf.get("cpu_copy", TINY))
         c["file"] = f"configs/{c['name']}.json"
         (tmp / c["file"]).write_text(json.dumps(conf))
     for w in bench["workloads"]:
         mix = json.loads((harness.HERE / "traffic" /
                           f"{w['traffic']}.json").read_text())
-        mix.update(TRAIN)
+        mix.update(mix.get("cpu_copy", TRAIN))
         (tmp / "traffic" / f"{w['traffic']}.json").write_text(
             json.dumps(mix))
         name = f"{w['config']}.{w['traffic']}.json"
